@@ -28,6 +28,9 @@ def _write_record(mod_name: str, result, rows: list[dict]) -> None:
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     from . import (bulk_placement_bench, cms_case_study, common,
                    fig4_group_split, fig6_priority, fig7_8_queue_exec,
                    fig9_11_migration, hier_bench, kernels_bench,

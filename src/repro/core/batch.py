@@ -20,8 +20,8 @@ are bit-identical to the per-job loop:
   all three branches.
 * ``batched_cost_matrix`` assembles the per-class (J, S) matrix in one
   shot; ``backend="kernel"`` routes through the Pallas §IV kernel
-  (``repro.kernels.cost_matrix``) — compiled on TPU, ``interpret=True``
-  on CPU — while ``backend="numpy"`` is the bit-exact reference path.
+  (``repro.kernels.cost_matrix``) compiled for the TPU, while
+  ``backend="numpy"`` is the bit-exact reference path.
 * ``replay_place`` commits placements sequentially-equivalently: the
   static planes are computed once, and only the cheap dynamic
   computation term is re-evaluated per row from the running
@@ -319,14 +319,11 @@ def batched_cost_matrix(
     """One-shot per-class §IV cost over (J, S); dead sites +inf.
 
     ``backend="numpy"``  — float64, bit-identical to the scalar loop.
-    ``backend="kernel"`` — the Pallas §IV kernel (float32; compiled on
-    TPU, interpreted elsewhere) via ``repro.kernels.cost_matrix``.
-    ``backend="auto"``   — kernel on TPU, NumPy otherwise.
+    ``backend="kernel"`` — the Pallas §IV kernel (float32) via
+    ``repro.kernels.cost_matrix``, always the kernel and never its jnp
+    reference. Off the TPU it raises unless the caller traced it under
+    ``jax.experimental.pallas.tpu.force_tpu_interpret_mode()``.
     """
-    if backend == "auto":
-        import jax
-
-        backend = "kernel" if jax.default_backend() == "tpu" else "numpy"
     if backend == "kernel":
         from repro.kernels.cost_matrix.ops import cost_matrix_classed
 

@@ -51,7 +51,9 @@ class PlacementEngine:
         mask_dead: bool = True,
         backend: str = "numpy",
     ) -> np.ndarray:
-        """Per-class (J, S) §IV cost over the view; dead sites +inf."""
+        """Per-class (J, S) §IV cost over the view; dead sites +inf.
+        ``backend`` is ``"numpy"`` (float64 reference) or ``"kernel"``
+        (the Pallas kernel on the TPU), as in ``batched_cost_matrix``."""
         return batched_cost_matrix(
             jp, sp, self.weights, mask_dead=mask_dead, backend=backend
         )

@@ -1,5 +1,7 @@
 """jit'd wrapper: pads jobs/sites to tile multiples, packs site state
-into the (8, S) row layout, runs kernel or oracle, adds the argmin."""
+into the (8, S) row layout, runs the kernel (or the oracle when the
+caller passes ``use_kernel=False``), adds the argmin. The kernel is
+compiled for the TPU unless the caller passes ``interpret=True``."""
 from __future__ import annotations
 
 import functools
@@ -34,7 +36,7 @@ def _pack_site_rows(cap, queue, work, load, bw, loss, rtt, alive, mss=1460.0):
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def cost_matrix(
     job_bytes, job_work, cap, queue, work, load, bw, loss, rtt, alive,
-    *, use_kernel=None, interpret=True,
+    *, use_kernel=True, interpret=False,
 ):
     """§IV cost over (J, S) + per-job best site. Returns (cost, best).
 
@@ -55,7 +57,7 @@ def cost_matrix(
 def cost_matrix_classed(
     job_bytes, job_work, job_wcomp, job_wdtc,
     cap, queue, work, load, bw, loss, rtt, alive, mss=1460.0,
-    *, w_queue=1.0, w_work=1.0, w_load=1.0, use_kernel=None, interpret=True,
+    *, w_queue=1.0, w_work=1.0, w_load=1.0, use_kernel=True, interpret=False,
 ):
     """§V per-class cost over (J, S): net + wcomp·comp + wdtc·dtc.
 
@@ -65,8 +67,6 @@ def cost_matrix_classed(
     BOTH. ``mss`` is the Mathis TCP segment size, scalar or per-link
     (S,). Returns ``(cost, best)`` like ``cost_matrix``.
     """
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
     if not use_kernel:
         return cost_matrix_classed_ref(
             job_bytes, job_work, job_wcomp, job_wdtc,
@@ -84,6 +84,6 @@ def cost_matrix_classed(
         jb[:, None], jw[:, None], site_rows,
         job_wcomp=wc[:, None], job_wdtc=wd[:, None],
         w_queue=w_queue, w_work=w_work, w_load=w_load,
-        interpret=(interpret and jax.default_backend() != "tpu"),
+        interpret=interpret,
     )[:J, :S]
     return cost, jnp.argmin(cost, axis=1).astype(jnp.int32)

@@ -14,10 +14,8 @@ from .ref import decode_attention_ref
 @functools.partial(
     jax.jit, static_argnames=("window", "softcap", "use_kernel", "interpret"))
 def decode_attention(q, k, v, pos, *, window=0, softcap=0.0,
-                     use_kernel=None, interpret=True):
+                     use_kernel=True, interpret=False):
     """q: (B, H, D); k, v: (B, S, KV, D); pos scalar → (B, H, D)."""
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
     if not use_kernel:
         return decode_attention_ref(q, k, v, pos, window=window, softcap=softcap)
     B, H, D = q.shape
@@ -27,6 +25,6 @@ def decode_attention(q, k, v, pos, *, window=0, softcap=0.0,
     out = decode_attention_pallas(
         qk, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), pos,
         window=window, softcap=softcap,
-        interpret=(interpret and jax.default_backend() != "tpu"),
+        interpret=interpret,
     )
     return out.reshape(B, H, D)

@@ -1,8 +1,7 @@
 """jit'd wrapper around the flash_attention kernel.
 
-Layout: models use (B, S, H, D); the kernel wants (B, H, S, D). On CPU
-the jnp oracle runs instead (the chunked path in
-``repro.models.attention`` is the production CPU/compile fallback)."""
+Layout: models use (B, S, H, D); the kernel wants (B, H, S, D).
+``use_kernel=False`` runs the jnp oracle instead."""
 from __future__ import annotations
 
 import functools
@@ -19,16 +18,14 @@ from .ref import flash_attention_ref
     static_argnames=("causal", "window", "softcap", "use_kernel", "interpret"),
 )
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    use_kernel=None, interpret=True):
+                    use_kernel=True, interpret=False):
     """q: (B, Sq, H, D); k, v: (B, Sk, KV, D) → (B, Sq, H, D)."""
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
     if not use_kernel:
         return flash_attention_ref(q, k, v, causal=causal, window=window,
                                    softcap=softcap)
     out = flash_attention_pallas(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
         causal=causal, window=window, softcap=softcap,
-        interpret=(interpret and jax.default_backend() != "tpu"),
+        interpret=interpret,
     )
     return out.transpose(0, 2, 1, 3)

@@ -1,5 +1,6 @@
 """jit'd wrapper: pad/reshape (L,) job arrays to lane-aligned (M, 128)
-tiles, run the Pallas kernel (TPU) or the jnp oracle (CPU), unpad."""
+tiles, run the Pallas kernel (or the jnp oracle when the caller passes
+``use_kernel=False``), unpad."""
 from __future__ import annotations
 
 import functools
@@ -21,10 +22,8 @@ def _pad_to_tiles(x, rows_multiple=64):
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def priority_requeue(n, q, t, quota_sum, proc_sum, *, use_kernel=None, interpret=True):
+def priority_requeue(n, q, t, quota_sum, proc_sum, *, use_kernel=True, interpret=False):
     """§X re-prioritization over L queued jobs → (pr (L,), qidx (L,))."""
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
     if not use_kernel:
         return priority_requeue_ref(n, q, t, quota_sum, proc_sum)
     n2, L = _pad_to_tiles(jnp.asarray(n, jnp.float32))
@@ -32,6 +31,6 @@ def priority_requeue(n, q, t, quota_sum, proc_sum, *, use_kernel=None, interpret
     t2, _ = _pad_to_tiles(jnp.asarray(t, jnp.float32))
     pr, qidx = priority_requeue_pallas(
         n2, q2, t2, quota_sum, proc_sum,
-        interpret=(interpret and jax.default_backend() != "tpu"),
+        interpret=interpret,
     )
     return pr.reshape(-1)[:L], qidx.reshape(-1)[:L]

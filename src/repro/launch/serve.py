@@ -2,6 +2,10 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch gemma2-9b --reduced \
         --requests 16 --slots 4
+    PYTHONPATH=src python -m repro.launch.serve --arch recurrentgemma-2b \
+        --no-reduced --requests 8 --slots 4 --prompt-len 16 --new-tokens 16
+
+``--no-reduced`` serves the published full-width configuration.
 """
 import argparse
 import time
@@ -11,13 +15,15 @@ import numpy as np
 
 from repro.configs import get_config, list_archs
 from repro.models import LM
+from repro.runtime.compile_cache import setup_compile_cache
 from repro.serving import InferenceRequest, ServingEngine
 
 
 def main(argv=None):
+    """Serve ``--requests`` random prompts; returns ``(engine, requests)``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-9b", choices=list_archs())
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction, default=True)
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=64)
@@ -25,9 +31,10 @@ def main(argv=None):
     ap.add_argument("--new-tokens", type=int, default=8)
     args = ap.parse_args(argv)
 
+    setup_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced).replace(remat=False)
     lm = LM(cfg)
-    params = lm.init(jax.random.PRNGKey(0))
+    params = jax.jit(lm.init)(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     engine = ServingEngine(lm, params, num_slots=args.slots,
                            max_len=args.max_len,
@@ -47,6 +54,7 @@ def main(argv=None):
     print(f"served={stats.served}/{args.requests} batches={stats.batches} "
           f"decode_steps={stats.decode_steps} tokens={tokens} "
           f"({tokens / dt:.1f} tok/s wall)")
+    return engine, reqs
 
 
 if __name__ == "__main__":

@@ -127,8 +127,10 @@ class ServingEngine:
             if r.prompt.tobytes() in self.prefix_cache:
                 self.stats.prefix_hits += 1
             self.prefix_cache.add(r.prompt.tobytes())
-        # prefill: lockstep decode over the prompt (pos resets per batch;
-        # stale cache beyond pos is masked out)
+        # prefill: lockstep decode over the prompt (pos resets per batch).
+        # Stale K/V beyond pos would be masked out, but recurrent state
+        # (RG-LRU, SSM) has no mask, so every batch starts from zeros.
+        self.cache = jax.tree.map(jnp.zeros_like, self.cache)
         logits = None
         for t in range(plen):
             logits, self.cache = self._step_fn(

@@ -2,6 +2,7 @@
 kernel and bit-exact equivalence with the sequential §V loop."""
 import copy
 
+import jax.experimental.pallas.tpu as pltpu
 import numpy as np
 import pytest
 
@@ -99,8 +100,11 @@ class TestKernelParity:
         np.testing.assert_array_equal(np.asarray(bk), np.asarray(br))
 
         # NumPy float64 batch path agrees (dead sites +inf vs BIG mask).
+        # backend="kernel" compiles for the TPU; on the CPU the test asks
+        # Pallas for interpret mode.
         cn = batched_cost_matrix(jp, sp, backend="numpy")
-        ckk = batched_cost_matrix(jp, sp, backend="kernel")
+        with pltpu.force_tpu_interpret_mode():
+            ckk = batched_cost_matrix(jp, sp, backend="kernel")
         assert cn.shape == (J, S)
         dead = ~sp.alive
         assert np.all(np.isinf(cn[:, dead]))
@@ -108,6 +112,19 @@ class TestKernelParity:
         np.testing.assert_allclose(
             ckk[:, alive_cols], cn[:, alive_cols], rtol=2e-4, atol=1e-4
         )
+
+    def test_kernel_backend_never_falls_back(self):
+        """backend="kernel" runs the Pallas kernel or raises: off the TPU
+        it needs interpret mode asked for (the parity test above does),
+        and no "auto" backend exists."""
+        rng = np.random.default_rng(11)
+        sites, links = _grid(rng, 9)
+        sp = SitePack.from_scheduler(sites, links)
+        jp = JobPack.from_jobs(_jobs(rng, 13))
+        with pytest.raises(ValueError, match="interpret mode"):
+            batched_cost_matrix(jp, sp, backend="kernel")
+        with pytest.raises(ValueError, match="unknown backend"):
+            batched_cost_matrix(jp, sp, backend="auto")
 
     def test_lossless_links_have_zero_network_cost(self):
         rng = np.random.default_rng(0)

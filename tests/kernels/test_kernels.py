@@ -9,8 +9,10 @@ from repro.kernels.priority_requeue.ref import priority_requeue_ref
 from repro.kernels.cost_matrix.ops import cost_matrix
 from repro.kernels.cost_matrix.ref import cost_matrix_ref
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.decode_attention.decode_attention import decode_attention_pallas
+from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 
 
@@ -136,3 +138,49 @@ class TestDecodeAttention:
         np.testing.assert_allclose(
             np.asarray(out_k, np.float32), np.asarray(out_r, np.float32),
             rtol=tol, atol=tol)
+
+
+def _wrapper_case(name):
+    """(jitted ops wrapper, positional args, its jnp oracle) at a small size."""
+    rng = np.random.default_rng(7)
+    u = lambda lo, hi, n: rng.uniform(lo, hi, n).astype(np.float32)
+    if name == "priority_requeue":
+        n, q, t = u(1, 50, 37), u(10, 5000, 37), u(1, 64, 37)
+        args = (n, q, t, float(q.sum()), float(t.sum()))
+        return priority_requeue, args, priority_requeue_ref
+    if name == "cost_matrix":
+        args = (u(0, 1e10, 5), u(1, 100, 5), u(10, 1000, 3), u(0, 50, 3),
+                u(0, 500, 3), u(0, 1, 3), u(1e8, 1e10, 3), u(0, 0.05, 3),
+                u(0.01, 0.3, 3), np.ones(3, np.float32))
+        return cost_matrix, args, cost_matrix_ref
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    if name == "flash_attention":
+        q = jax.random.normal(ks[0], (1, 128, 2, 64)) * 0.5
+        k = jax.random.normal(ks[1], (1, 128, 2, 64)) * 0.5
+        v = jax.random.normal(ks[2], (1, 128, 2, 64)) * 0.5
+        return flash_attention, (q, k, v), flash_attention_ref
+    q = jax.random.normal(ks[0], (1, 4, 64)) * 0.5
+    k = jax.random.normal(ks[1], (1, 128, 2, 64)) * 0.5
+    v = jax.random.normal(ks[2], (1, 128, 2, 64)) * 0.5
+    return decode_attention, (q, k, v, 100), decode_attention_ref
+
+
+class TestWrapperDispatch:
+    """The ops wrappers pick kernel, oracle and interpret mode from their
+    arguments alone, never from ``jax.default_backend()``."""
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    @pytest.mark.parametrize(
+        "name",
+        ["priority_requeue", "cost_matrix", "flash_attention", "decode_attention"])
+    def test_same_path_whatever_the_backend_reports(self, name, backend, monkeypatch):
+        fn, args, ref = _wrapper_case(name)
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        jax.clear_caches()          # retrace: no cached program from another case
+        with pytest.raises(ValueError, match="interpret mode"):
+            fn(*args)               # default: the compiled kernel, which a CPU refuses
+        expect = jax.tree.leaves(ref(*args))
+        for got in (fn(*args, use_kernel=False), fn(*args, interpret=True)):
+            for g, e in zip(jax.tree.leaves(got), expect):
+                np.testing.assert_allclose(np.asarray(g), np.asarray(e),
+                                           rtol=2e-5, atol=2e-5)

@@ -12,18 +12,12 @@ REPO = Path(__file__).resolve().parents[2]
 
 pytestmark = pytest.mark.slow  # multi-minute subprocess compiles
 
-# Pre-existing seed failure: the repro.launch mesh helpers call
-# jax.sharding.AxisType, which the pinned jax build predates.
-AXISTYPE_XFAIL = pytest.mark.xfail(
-    strict=False,
-    reason="installed jax predates jax.sharding.AxisType (repro.launch mesh setup)",
-)
-
 
 def _run_cell(tmp_path, arch, shape, mesh="2x4"):
+    # CPU virtual devices: the child must not contend for a TPU
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=str(REPO / "src"))
+               PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
            "--shape", shape, "--mesh", mesh, "--reduced",
            "--out", str(tmp_path)]
@@ -41,7 +35,6 @@ def _run_cell(tmp_path, arch, shape, mesh="2x4"):
     ("mamba2-780m", "decode_32k"),       # SSM decode cache
     ("recurrentgemma-2b", "prefill_32k"),  # hybrid periods
 ])
-@AXISTYPE_XFAIL
 def test_reduced_cell_compiles_and_reports(tmp_path, arch, shape):
     rec = _run_cell(tmp_path, arch, shape)
     assert rec["arch"] == arch
@@ -54,7 +47,6 @@ def test_reduced_cell_compiles_and_reports(tmp_path, arch, shape):
         assert rec["params"]["total"] > 0
 
 
-@AXISTYPE_XFAIL
 def test_multi_pod_axis_shards(tmp_path):
     """The 'pod' axis must actually divide the work: a 2x2x2 mesh
     compiles and the batch shards over (pod, data)."""

@@ -51,6 +51,29 @@ class TestServingEngine:
             outs.append(r.generated)
         assert outs[0] == outs[1]
 
+    def test_later_batch_starts_from_empty_recurrent_state(self):
+        """A hybrid (RG-LRU) model carries state that no mask hides: a
+        prompt served after another batch must decode exactly as when
+        served alone."""
+        cfg = get_config("recurrentgemma-2b", reduced=True).replace(
+            remat=False, param_dtype="float32", compute_dtype="float32")
+        lm = LM(cfg)
+        params = lm.init(jax.random.PRNGKey(0))
+        rng = np.random.default_rng(6)
+        # one-token prompts: the conv tail of the earlier batch would
+        # reach the probe's first step directly
+        first, probe = (_req(cfg, "u", rng, plen=1) for _ in range(2))
+        alone = InferenceRequest(user="u", prompt=probe.prompt.copy(),
+                                 max_new_tokens=probe.max_new_tokens)
+        eng = ServingEngine(lm, params, num_slots=1, max_len=32)
+        eng.submit(first, now=0.0)
+        eng.submit(probe, now=1.0)
+        assert eng.run_until_drained().batches == 2
+        eng = ServingEngine(lm, params, num_slots=1, max_len=32)
+        eng.submit(alone)
+        eng.run_until_drained()
+        assert probe.generated == alone.generated
+
     def test_quota_priority_orders_batches(self, engine_setup):
         """§X: high-quota tenant jumps the low-quota flood."""
         cfg, lm, params = engine_setup
