@@ -1,9 +1,9 @@
-"""Benchmark harness — one module per paper table/figure plus the
-roofline and kernel micro-benches. Prints ``name,us_per_call,derived``
-CSV rows (paper-expected values embedded in the derived field) and
-writes each module's results to ``BENCH_<module>.json`` at the repo
-root: the ``emit``-ed rows plus, when the module's ``run()`` returns a
-dict, that machine-readable result record."""
+"""Benchmark harness — one module per paper table/figure and per
+subsystem. Prints ``name,us_per_call,derived`` CSV rows
+(paper-expected values embedded in the derived field) and writes each
+module's results to ``BENCH_<module>.json`` at the repo root: the
+``emit``-ed rows plus, when the module's ``run()`` returns a dict, that
+machine-readable result record."""
 from __future__ import annotations
 
 import json
@@ -33,17 +33,16 @@ def main() -> None:
     setup_compile_cache()
     from . import (bulk_placement_bench, cms_case_study, common,
                    fig4_group_split, fig6_priority, fig7_8_queue_exec,
-                   fig9_11_migration, hier_bench, kernels_bench,
-                   migration_bench, p2p_bench, roofline, scenarios_bench,
-                   serving_bench, streaming_bench)
+                   fig9_11_migration, hier_bench, migration_bench,
+                   p2p_bench, scenarios_bench, serving_bench,
+                   streaming_bench)
 
     print("name,us_per_call,derived")
     failures = 0
     for mod in (fig4_group_split, fig6_priority, fig7_8_queue_exec,
                 fig9_11_migration, migration_bench, p2p_bench,
                 streaming_bench, cms_case_study, bulk_placement_bench,
-                hier_bench, scenarios_bench, roofline, kernels_bench,
-                serving_bench):
+                hier_bench, scenarios_bench, serving_bench):
         short = mod.__name__.rsplit(".", 1)[-1]
         common.drain_records()
         try:

@@ -38,6 +38,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from . import trace
 from .costs import CostWeights, NetworkLink, SiteState
 from .queues import Job
 from .scheduler import JobClass, classify
@@ -50,6 +51,7 @@ __all__ = [
     "TierPack",
     "argmin_finite",
     "class_total",
+    "commit_placement",
     "comp_site_column",
     "cost_components",
     "batched_cost_matrix",
@@ -496,40 +498,42 @@ def replay_on_pack(
     same arrays. Site choices and costs are bit-identical to the
     sequential per-job loop over the same view.
     """
-    net, comp_base, dtc = cost_components(jp, sp, weights)
-    comp_base = comp_base.copy()
-    dead = ~sp.alive
-    # Dead sites poison every class branch through the (always-present)
-    # network plane: +inf propagates through the remaining additions.
-    net_m = np.where(dead, np.inf, net)
-    dtc_m = dtc.copy()
-    dtc_m[:, dead] = np.inf
+    with trace.span("diana.plane"):
+        net, comp_base, dtc = cost_components(jp, sp, weights)
+        comp_base = comp_base.copy()
+        dead = ~sp.alive
+        # Dead sites poison every class branch through the (always-present)
+        # network plane: +inf propagates through the remaining additions.
+        net_m = np.where(dead, np.inf, net)
+        dtc_m = dtc.copy()
+        dtc_m[:, dead] = np.inf
 
-    q = sp.queue.copy()
-    w = sp.work.copy()
-    wq, ww = weights.w_queue, weights.w_work
-    load_term = weights.w_load * sp.load
-    cap = sp.cap
+    with trace.span("diana.replay"):
+        q = sp.queue.copy()
+        w = sp.work.copy()
+        wq, ww = weights.w_queue, weights.w_work
+        load_term = weights.w_load * sp.load
+        cap = sp.cap
 
-    J = len(jp.classes)
-    site_idx = np.empty(J, np.int64)
-    costs = np.empty(J, np.float64)
-    for j in range(J):
-        cls = jp.classes[j]
-        comp = None if cls is JobClass.DATA else comp_base + jp.work[j] / cap
-        row = class_total(cls, net_m, comp, dtc_m[j])
-        s, cost = argmin_finite(row)
-        site_idx[j] = s
-        costs[j] = cost
-        q[s] += 1.0
-        w[s] += jp.work[j]
-        # Only site s changed; re-derive its entry with comp_site_column's
-        # elementwise expression so the value stays bit-identical to a
-        # full recomputation.
-        comp_base[s] = (wq * q[s] / cap[s] + ww * w[s] / cap[s]) + load_term[s]
+        J = len(jp.classes)
+        site_idx = np.empty(J, np.int64)
+        costs = np.empty(J, np.float64)
+        for j in range(J):
+            cls = jp.classes[j]
+            comp = None if cls is JobClass.DATA else comp_base + jp.work[j] / cap
+            row = class_total(cls, net_m, comp, dtc_m[j])
+            s, cost = argmin_finite(row)
+            site_idx[j] = s
+            costs[j] = cost
+            q[s] += 1.0
+            w[s] += jp.work[j]
+            # Only site s changed; re-derive its entry with comp_site_column's
+            # elementwise expression so the value stays bit-identical to a
+            # full recomputation.
+            comp_base[s] = (wq * q[s] / cap[s] + ww * w[s] / cap[s]) + load_term[s]
 
-    sp.queue[:] = q
-    sp.work[:] = w
+        sp.queue[:] = q
+        sp.work[:] = w
     return BatchPlacement(
         site_indices=site_idx,
         sites=[sp.names[i] for i in site_idx],
@@ -552,16 +556,29 @@ def replay_place(
     the resulting queue/work vectors back — site choices, costs and
     final site state are bit-identical to the sequential loop.
     """
-    sp = SitePack.from_scheduler(sites, links)
-    jp = JobPack.from_jobs(jobs, job_classes)
+    with trace.span("diana.pack"):
+        sp = SitePack.from_scheduler(sites, links)
+        jp = JobPack.from_jobs(jobs, job_classes)
     placement = replay_on_pack(jp, sp, weights)
     if commit:
+        commit_placement(jobs, placement, sites, sp)
+    return placement
+
+
+def commit_placement(
+    jobs: Sequence[Job],
+    placement: BatchPlacement,
+    sites: dict[str, SiteState],
+    sp: SitePack,
+) -> None:
+    """Write a replay's results back: each job's site, and each
+    site's queue length and waiting work from the pack."""
+    with trace.span("diana.commit"):
         for job, name in zip(jobs, placement.sites):
             job.site = name
         for i, name in enumerate(sp.names):
             sites[name].queue_length = float(sp.queue[i])
             sites[name].waiting_work = float(sp.work[i])
-    return placement
 
 
 # ---------------------------------------------------------------------------
@@ -759,10 +776,11 @@ def _hier_argmin_row(
     comp_base: np.ndarray,
     comp_min: np.ndarray,
     use32: bool,
-) -> tuple[int, float]:
+) -> tuple[int, float, int, int]:
     """One job's two-level argmin: ``(column, cost)`` bit-identical to
     ``argmin_finite`` over the flat dense row, or ``(-1, inf)`` when no
-    alive/finite column exists.
+    alive/finite column exists, followed by the number of tiers refined
+    and of columns evaluated in f64 on the way.
 
     ``comp_base`` is the job-independent computation column (the full
     per-job term is ``comp_base + work_j / cap``); ``comp_min`` its
@@ -797,6 +815,7 @@ def _hier_argmin_row(
 
     best_cost = np.inf
     best_col = -1
+    tiers = cols_refined = 0
     for t in np.argsort(bound, kind="stable"):
         t = int(t)
         # <= (not <): a runner-up tier whose bound ties the refined best
@@ -829,6 +848,8 @@ def _hier_argmin_row(
         # Exact f64 refinement on the shortlist: elementwise ops on
         # column slices equal the sliced full-vector results, so these
         # values match the flat dense row bit for bit.
+        tiers += 1
+        cols_refined += len(short)
         comp_s = None
         if has_comp:
             comp_s = comp_base[short] + work_j / sp.cap[short]
@@ -846,7 +867,12 @@ def _hier_argmin_row(
             col = int(short[k])
             if c < best_cost or (c == best_cost and col < best_col):
                 best_cost, best_col = c, col
-    return best_col, best_cost
+    return best_col, best_cost, tiers, cols_refined
+
+
+def _count_refined(tiers: int, cols: int) -> None:
+    trace.count("diana.hier.tiers_refined", tiers)
+    trace.count("diana.hier.cols_refined", cols)
 
 
 def hier_select(
@@ -865,8 +891,9 @@ def hier_select(
     J = len(jp.classes)
     idx = np.empty(J, np.int64)
     costs = np.empty(J, np.float64)
+    tiers = cols = 0
     for j in range(J):
-        col, c = _hier_argmin_row(
+        col, c, nt, nc = _hier_argmin_row(
             tp, sp, jp.classes[j],
             float(jp.bytes_[j]), float(jp.work[j]),
             comp_site, comp_min, use32,
@@ -875,6 +902,9 @@ def hier_select(
             raise RuntimeError("no alive site available")
         idx[j] = col
         costs[j] = c
+        tiers += nt
+        cols += nc
+    _count_refined(tiers, cols)
     return BatchPlacement(
         site_indices=idx,
         sites=[sp.names[i] for i in idx],
@@ -893,42 +923,48 @@ def hier_replay(
     same sequential queue/work feedback between rows (written back to
     the pack), same choices and costs, but each row is resolved through
     the tier bounds instead of a dense (S,) scan."""
-    comp_base = comp_site_column(sp, weights).copy()
-    comp_min = tp.comp_tier_min(comp_base)
-    use32 = _f32_gate(jp, sp, tp, weights)
-    q = sp.queue.copy()
-    w = sp.work.copy()
-    wq, ww = weights.w_queue, weights.w_work
-    load_term = weights.w_load * sp.load
-    cap = sp.cap
-    J = len(jp.classes)
-    site_idx = np.empty(J, np.int64)
-    costs = np.empty(J, np.float64)
-    for j in range(J):
-        col, c = _hier_argmin_row(
-            tp, sp, jp.classes[j],
-            float(jp.bytes_[j]), float(jp.work[j]),
-            comp_base, comp_min, use32,
-        )
-        if col < 0:
-            raise RuntimeError("no alive site available")
-        site_idx[j] = col
-        costs[j] = c
-        s = col
-        q[s] += 1.0
-        w[s] += jp.work[j]
-        old = comp_base[s]
-        # Same elementwise expression as comp_site_column so the value
-        # stays bit-identical to a full recomputation (replay_on_pack).
-        comp_base[s] = (wq * q[s] / cap[s] + ww * w[s] / cap[s]) + load_term[s]
-        t = int(tp.tier_of[s])
-        if comp_base[s] < comp_min[t]:
-            comp_min[t] = comp_base[s]
-        elif old == comp_min[t] and comp_base[s] != old:
-            # The tier minimum itself moved up: re-aggregate exactly.
-            comp_min[t] = comp_base[tp.members[t]].min()
-    sp.queue[:] = q
-    sp.work[:] = w
+    with trace.span("diana.plane"):
+        comp_base = comp_site_column(sp, weights).copy()
+        comp_min = tp.comp_tier_min(comp_base)
+        use32 = _f32_gate(jp, sp, tp, weights)
+    with trace.span("diana.replay"):
+        q = sp.queue.copy()
+        w = sp.work.copy()
+        wq, ww = weights.w_queue, weights.w_work
+        load_term = weights.w_load * sp.load
+        cap = sp.cap
+        J = len(jp.classes)
+        site_idx = np.empty(J, np.int64)
+        costs = np.empty(J, np.float64)
+        tiers = cols = 0
+        for j in range(J):
+            col, c, nt, nc = _hier_argmin_row(
+                tp, sp, jp.classes[j],
+                float(jp.bytes_[j]), float(jp.work[j]),
+                comp_base, comp_min, use32,
+            )
+            if col < 0:
+                raise RuntimeError("no alive site available")
+            site_idx[j] = col
+            costs[j] = c
+            tiers += nt
+            cols += nc
+            s = col
+            q[s] += 1.0
+            w[s] += jp.work[j]
+            old = comp_base[s]
+            # Same elementwise expression as comp_site_column so the value
+            # stays bit-identical to a full recomputation (replay_on_pack).
+            comp_base[s] = (wq * q[s] / cap[s] + ww * w[s] / cap[s]) + load_term[s]
+            t = int(tp.tier_of[s])
+            if comp_base[s] < comp_min[t]:
+                comp_min[t] = comp_base[s]
+            elif old == comp_min[t] and comp_base[s] != old:
+                # The tier minimum itself moved up: re-aggregate exactly.
+                comp_min[t] = comp_base[tp.members[t]].min()
+        sp.queue[:] = q
+        sp.work[:] = w
+    _count_refined(tiers, cols)
     return BatchPlacement(
         site_indices=site_idx,
         sites=[sp.names[i] for i in site_idx],

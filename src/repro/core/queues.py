@@ -21,6 +21,7 @@ from typing import Iterable, Optional
 import numpy as np
 
 from . import priority as prio
+from . import trace
 
 __all__ = ["Job", "MultilevelFeedbackQueues", "is_congested"]
 
@@ -122,15 +123,18 @@ class MultilevelFeedbackQueues:
         """Recompute Pr for every queued job with current (Q, T) (§X)."""
         if not self.jobs:
             return
-        Q, T = self._totals()
-        counts = self._user_counts()
-        n = np.array([counts[j.user] for j in self.jobs], np.float32)
-        q = np.array([self.quotas[j.user] for j in self.jobs], np.float32)
-        t = np.array([j.t for j in self.jobs], np.float32)
-        pr, qidx = prio.reprioritize_np(n, q, t, Q, T)
-        for j, p, qi in zip(self.jobs, pr, qidx):
-            j.priority = float(p)
-            j.queue = int(qi)
+        trace.count("diana.mlfq.submits")
+        trace.count("diana.mlfq.reprioritized", len(self.jobs))
+        with trace.span("diana.mlfq.reprioritize"):
+            Q, T = self._totals()
+            counts = self._user_counts()
+            n = np.array([counts[j.user] for j in self.jobs], np.float32)
+            q = np.array([self.quotas[j.user] for j in self.jobs], np.float32)
+            t = np.array([j.t for j in self.jobs], np.float32)
+            pr, qidx = prio.reprioritize_np(n, q, t, Q, T)
+            for j, p, qi in zip(self.jobs, pr, qidx):
+                j.priority = float(p)
+                j.queue = int(qi)
 
     # -- service ------------------------------------------------------------
     def pop_next(self, now: Optional[float] = None) -> Optional[Job]:
@@ -140,16 +144,17 @@ class MultilevelFeedbackQueues:
         """
         if not self.jobs:
             return None
-        best = min(
-            self.jobs,
-            key=lambda j: (-j.priority, j.submit_time, j.job_id),
-        )
-        self.jobs.remove(best)
-        self._services += 1
-        if now is not None:
-            if self._service_times and now < self._service_times[-1]:
-                self._rate_monotone = False
-            self._service_times.append(now)
+        with trace.span("diana.mlfq.pop"):
+            best = min(
+                self.jobs,
+                key=lambda j: (-j.priority, j.submit_time, j.job_id),
+            )
+            self.jobs.remove(best)
+            self._services += 1
+            if now is not None:
+                if self._service_times and now < self._service_times[-1]:
+                    self._rate_monotone = False
+                self._service_times.append(now)
         return best
 
     def remove(self, job: Job) -> None:

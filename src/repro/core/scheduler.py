@@ -29,6 +29,7 @@ from .costs import (
     data_transfer_cost,
     network_cost,
 )
+from . import trace
 from .queues import Job
 
 __all__ = ["JobClass", "classify", "DianaScheduler", "SiteDecision"]
@@ -207,24 +208,25 @@ class DianaScheduler:
         """
         from . import batch as _batch
 
-        if mode == "hier":
-            sp = _batch.SitePack.from_scheduler(self.sites, self.links)
-            jp = self.engine.pack_jobs(jobs, job_classes)
-            tp = _batch.TierPack.from_site_pack(
-                sp, self.topology if tiers is None else tiers
-            )
-            placement = self.engine.replay_hier(jp, sp, tp)
-            for job, name in zip(jobs, placement.sites):
-                job.site = name
-            for i, name in enumerate(sp.names):
-                self.sites[name].queue_length = float(sp.queue[i])
-                self.sites[name].waiting_work = float(sp.work[i])
-            return placement
-        if mode != "flat":
+        if mode not in ("flat", "hier"):
             raise ValueError(f"mode must be 'flat' or 'hier', got {mode!r}")
-        return _batch.replay_place(
-            jobs, self.sites, self.links, self.weights, job_classes, commit=True
-        )
+        with trace.span("diana.place_batch"):
+            if mode == "hier":
+                with trace.span("diana.pack"):
+                    sp = _batch.SitePack.from_scheduler(self.sites, self.links)
+                    jp = self.engine.pack_jobs(jobs, job_classes)
+                    tp = _batch.TierPack.from_site_pack(
+                        sp, self.topology if tiers is None else tiers
+                    )
+                placement = self.engine.replay_hier(jp, sp, tp)
+                _batch.commit_placement(jobs, placement, self.sites, sp)
+            else:
+                placement = _batch.replay_place(
+                    jobs, self.sites, self.links, self.weights, job_classes, commit=True
+                )
+            trace.count("diana.calls")
+            trace.count("diana.jobs_placed", len(placement.sites))
+        return placement
 
     def complete(self, job: Job) -> None:
         """Release a finished job's claim on its site."""

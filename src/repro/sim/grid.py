@@ -39,6 +39,7 @@ from repro.core import (
     network_cost,
     select_peer,
 )
+from repro.core import trace
 from repro.core.batch import comp_site_column
 from repro.core.bulk import stable_user_peer
 from repro.core.migration import (
@@ -726,38 +727,39 @@ class GridSim:
         implementations (``horizon`` on/off) produce bit-identical
         results on the same workload.
         """
-        source = as_arrival_source(jobs)
-        input_list = jobs if isinstance(jobs, list) else None
-        horizon_t = until if until is not None else float("inf")
-        plan = self.config.fault_plan
-        if plan is not None:
-            plan.validate(
-                sites=set(self.sites),
-                num_peers=getattr(self, "num_peers", None),
-            )
-        # Every run replays its fault plan from a clean slate (and a
-        # previous truncated run must not leak liveness/link damage
-        # into a plain re-run either).
-        self._reset_faults()
-        self._stats = StreamStats()
-        # Derived-value caches never survive into a run: the caller may
-        # have mutated site state between runs.
-        self._comp_base = self._comp_ok = None
-        self._sp = None
-        self._sp_dirty = None
-        self._collect = [] if input_list is None and self.config.retain_jobs else None
-        cursor = _ArrivalCursor(source.chunks())
-        self._on_stream_start(cursor.peek_time())
-        if self.config.horizon:
-            self._run_horizon(cursor, horizon_t)
-            out_jobs = input_list if input_list is not None else (self._collect or [])
-        else:
-            materialized = input_list if input_list is not None else cursor.drain()
-            self._run_events(materialized, horizon_t)
-            out_jobs = materialized if (
-                input_list is not None or self.config.retain_jobs
-            ) else []
-        stats, self._stats, self._collect = self._stats, None, None
+        with trace.span("diana.sim.run"):
+            source = as_arrival_source(jobs)
+            input_list = jobs if isinstance(jobs, list) else None
+            horizon_t = until if until is not None else float("inf")
+            plan = self.config.fault_plan
+            if plan is not None:
+                plan.validate(
+                    sites=set(self.sites),
+                    num_peers=getattr(self, "num_peers", None),
+                )
+            # Every run replays its fault plan from a clean slate (and a
+            # previous truncated run must not leak liveness/link damage
+            # into a plain re-run either).
+            self._reset_faults()
+            self._stats = StreamStats()
+            # Derived-value caches never survive into a run: the caller may
+            # have mutated site state between runs.
+            self._comp_base = self._comp_ok = None
+            self._sp = None
+            self._sp_dirty = None
+            self._collect = [] if input_list is None and self.config.retain_jobs else None
+            cursor = _ArrivalCursor(source.chunks())
+            self._on_stream_start(cursor.peek_time())
+            if self.config.horizon:
+                self._run_horizon(cursor, horizon_t)
+                out_jobs = input_list if input_list is not None else (self._collect or [])
+            else:
+                materialized = input_list if input_list is not None else cursor.drain()
+                self._run_events(materialized, horizon_t)
+                out_jobs = materialized if (
+                    input_list is not None or self.config.retain_jobs
+                ) else []
+            stats, self._stats, self._collect = self._stats, None, None
         return SimResult(
             jobs=out_jobs, timeline=self.timeline, bucket_s=self.bucket_s,
             policy=self.policy, stats=stats,
@@ -797,17 +799,18 @@ class GridSim:
                 # Same-instant arrivals pop consecutively (their seqs are
                 # the lowest at that timestamp), so draining them here is
                 # order-identical to one-at-a-time processing.
-                if self.batch_arrivals and self.policy == "diana":
-                    batch = [payload]
-                    while events and events[0][0] == now and events[0][2] == "arrive":
-                        batch.append(heapq.heappop(events)[3])
-                    if len(batch) > 1 and self._batch_eligible(batch):
-                        self._on_arrive_batch(batch, now, events)
+                with trace.span("diana.sim.arrive"):
+                    if self.batch_arrivals and self.policy == "diana":
+                        batch = [payload]
+                        while events and events[0][0] == now and events[0][2] == "arrive":
+                            batch.append(heapq.heappop(events)[3])
+                        if len(batch) > 1 and self._batch_eligible(batch):
+                            self._on_arrive_batch(batch, now, events)
+                        else:
+                            for sj in batch:
+                                self._on_arrive(sj, now, events)
                     else:
-                        for sj in batch:
-                            self._on_arrive(sj, now, events)
-                else:
-                    self._on_arrive(payload, now, events)
+                        self._on_arrive(payload, now, events)
             elif kind == "finish":
                 site_name, cj, tok = payload
                 self._on_finish(site_name, cj, tok, now, events)
@@ -930,15 +933,16 @@ class GridSim:
         almost entirely single arrivals."""
         if not batch:
             return
-        if (
-            self.batch_arrivals
-            and self.policy == "diana"
-            and self._batch_eligible(batch)
-        ):
-            self._on_arrive_batch(batch, now, events)
-        else:
-            for sj in batch:
-                self._on_arrive(sj, now, events)
+        with trace.span("diana.sim.arrive"):
+            if (
+                self.batch_arrivals
+                and self.policy == "diana"
+                and self._batch_eligible(batch)
+            ):
+                self._on_arrive_batch(batch, now, events)
+            else:
+                for sj in batch:
+                    self._on_arrive(sj, now, events)
 
     def _work_remaining(self, events: list) -> bool:
         """Whether the periodic events (migrate/exchange) should keep
@@ -1256,46 +1260,47 @@ class GridSim:
         window and Q4 membership, so a later site's candidate set
         genuinely depends on earlier sites' moves — a global upfront
         collection could not stay bit-identical)."""
-        batched = (
-            self.batch_migration
-            and self.policy == "diana"
-            and self._link_matrices_ready()
-        )
-        if not batched:
+        with trace.span("diana.sim.migrate"):
+            batched = (
+                self.batch_migration
+                and self.policy == "diana"
+                and self._link_matrices_ready()
+            )
+            if not batched:
+                for name, site in self.sites.items():
+                    if (
+                        site.use_mlfq
+                        and site.alive
+                        and site.mlfq.congested(self.congestion_window_s, now)
+                    ):
+                        self._migrate_site_sequential(name, site, now, events)
+                return
+            self._mig_prio_cache.clear()
+            sp: Optional[SitePack] = None
+            idx = self._site_idx
             for name, site in self.sites.items():
-                if (
-                    site.use_mlfq
-                    and site.alive
-                    and site.mlfq.congested(self.congestion_window_s, now)
+                if not site.use_mlfq or not site.alive:
+                    continue
+                if not site.mlfq.congested(self.congestion_window_s, now):
+                    continue
+                cands = list(site.mlfq.low_priority_jobs())
+                if not cands:
+                    continue
+                sjs = [self._cj2sj[cj.job_id] for cj in cands]
+                if sp is None:
+                    sp = self._site_pack()
+                if not all(
+                    sj.origin_site in idx
+                    and (sj.data_site is None or sj.data_site in idx)
+                    for sj in sjs
                 ):
-                    self._migrate_site_sequential(name, site, now, events)
-            return
-        self._mig_prio_cache.clear()
-        sp: Optional[SitePack] = None
-        idx = self._site_idx
-        for name, site in self.sites.items():
-            if not site.use_mlfq or not site.alive:
-                continue
-            if not site.mlfq.congested(self.congestion_window_s, now):
-                continue
-            cands = list(site.mlfq.low_priority_jobs())
-            if not cands:
-                continue
-            sjs = [self._cj2sj[cj.job_id] for cj in cands]
-            if sp is None:
-                sp = self._site_pack()
-            if not all(
-                sj.origin_site in idx
-                and (sj.data_site is None or sj.data_site in idx)
-                for sj in sjs
-            ):
-                # Off-grid endpoints (e.g. a storage element) can't use
-                # the dense planes — fall back per job for this site and
-                # resync the packed state it mutated.
-                touched = self._migrate_site_sequential(name, site, now, events)
-                self._resync_pack(sp, touched)
-                continue
-            self._migrate_site_batched(name, site, cands, sjs, sp, now, events)
+                    # Off-grid endpoints (e.g. a storage element) can't use
+                    # the dense planes — fall back per job for this site and
+                    # resync the packed state it mutated.
+                    touched = self._migrate_site_sequential(name, site, now, events)
+                    self._resync_pack(sp, touched)
+                    continue
+                self._migrate_site_batched(name, site, cands, sjs, sp, now, events)
 
     def _migrate_site_sequential(
         self, name: str, site: _Site, now: float, events: list

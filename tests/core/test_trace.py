@@ -1,0 +1,126 @@
+"""Spans and counters (``repro.core.trace``): off by default and free of
+effect on decisions; on, the counters read what the calls did."""
+import copy
+
+import numpy as np
+import pytest
+
+from repro.core import DianaScheduler, Job, MultilevelFeedbackQueues, NetworkLink, SiteState
+from repro.core import trace
+
+
+@pytest.fixture
+def tracing_on():
+    trace.enable()
+    trace.reset()
+    try:
+        yield
+    finally:
+        trace.disable()
+        trace.reset()
+
+
+def _scheduler(seed, n_sites=24):
+    rng = np.random.default_rng(seed)
+    sites, links = {}, {}
+    for i in range(n_sites):
+        name = f"s{i:02d}"
+        sites[name] = SiteState(
+            name=name, capacity=float(rng.integers(10, 2000)),
+            queue_length=float(rng.integers(0, 50)),
+            waiting_work=float(rng.uniform(0, 500)),
+            load=float(rng.uniform(0, 1)),
+            alive=bool(i == 0 or rng.uniform() > 0.2),
+        )
+        links[name] = NetworkLink(
+            bandwidth_Bps=float(rng.uniform(1e6, 1e10)),
+            loss_rate=0.0 if rng.uniform() < 0.3 else float(rng.uniform(1e-4, 0.05)),
+            rtt_s=float(rng.uniform(0.001, 0.3)),
+        )
+    return DianaScheduler(sites, links)
+
+
+def _jobs(seed, n):
+    rng = np.random.default_rng(seed + 1)
+    return [
+        Job(user=f"u{i % 3}", compute_work=float(rng.uniform(0.1, 200)),
+            input_bytes=float(rng.choice([0.0, rng.uniform(0, 50e9)])),
+            output_bytes=float(rng.uniform(0, 1e9)))
+        for i in range(n)
+    ]
+
+
+def _tiers(sched, n_tiers=5):
+    return {n: f"t{i % n_tiers}" for i, n in enumerate(sched.sites)}
+
+
+def _place_rounds(sched, mode, rounds=3, n=30):
+    """Place ``rounds`` batches; returns everything a decision shows."""
+    out = []
+    tiers = _tiers(sched) if mode == "hier" else None
+    for r in range(rounds):
+        jobs = _jobs(r, n)
+        p = sched.place_batch(jobs, mode=mode, tiers=tiers)
+        out.append((
+            list(p.sites), p.costs.tolist(), [j.site for j in jobs],
+            {k: (s.queue_length, s.waiting_work) for k, s in sched.sites.items()},
+        ))
+    return out
+
+
+def test_off_by_default_span_is_shared_noop_and_counts_nothing():
+    assert not trace.on
+    assert trace.span("diana.pack") is trace.span("diana.replay")
+    with trace.span("diana.pack") as entered:
+        assert entered is None
+    for mode in ("flat", "hier"):
+        _place_rounds(_scheduler(3), mode)
+    q = MultilevelFeedbackQueues({"a": 1.0})
+    q.submit(Job(user="a"))
+    q.pop_next()
+    assert trace.counters() == {}
+
+
+@pytest.mark.parametrize("mode", ["flat", "hier"])
+def test_decisions_bit_identical_with_tracing_on(mode, tracing_on):
+    base = _scheduler(7)
+    traced = copy.deepcopy(base)
+    trace.disable()
+    off = _place_rounds(base, mode)
+    trace.enable()
+    on = _place_rounds(traced, mode)
+    assert on == off
+
+
+@pytest.mark.parametrize("mode", ["flat", "hier"])
+def test_calls_and_jobs_placed(mode, tracing_on):
+    _place_rounds(_scheduler(11), mode, rounds=4, n=17)
+    c = trace.counters()
+    assert c["diana.calls"] == 4
+    assert c["diana.jobs_placed"] == 4 * 17
+    trace.reset()
+    assert trace.counters() == {}
+
+
+def test_hier_refines_at_least_one_region_and_column_per_job(tracing_on):
+    J = 3 * 30
+    _place_rounds(_scheduler(13), "hier")
+    c = trace.counters()
+    assert c["diana.jobs_placed"] == J
+    assert c["diana.hier.cols_refined"] >= c["diana.hier.tiers_refined"] >= J
+    # at most every region of the five, once per job
+    assert c["diana.hier.tiers_refined"] <= 5 * J
+
+
+def test_reprioritized_counts_queue_depth_at_each_submit(tracing_on):
+    q = MultilevelFeedbackQueues({"a": 1.0, "b": 2.0})
+    depths = []
+    for k, user in enumerate("aabab"):
+        q.submit(Job(user=user, t=1.0 + k, submit_time=float(k)))
+        depths.append(len(q))
+        if k == 2:
+            q.pop_next()
+    assert depths == [1, 2, 3, 3, 4]
+    c = trace.counters()
+    assert c["diana.mlfq.submits"] == 5
+    assert c["diana.mlfq.reprioritized"] == sum(depths)
