@@ -32,6 +32,7 @@ are bit-identical to the per-job loop:
 """
 from __future__ import annotations
 
+import math
 import warnings
 from dataclasses import dataclass
 from typing import Optional, Sequence
@@ -587,12 +588,13 @@ def commit_placement(
 # A tier is a group of pack columns (a RootGrid of GridTopology, §IX).
 # Each tier carries an *admissible* optimistic summary — a lower bound
 # on every member's §IV cost built from per-component extrema
-# (min(a+b) >= min(a) + min(b)) — so jobs argmin over the (J, T) bound
-# matrix first and run the dense pass only inside the winning tier,
-# widening to runner-up tiers while their bound can still beat the
-# refined best. Refinement evaluates a cheap f32 score over the tier's
-# columns, shortlists everything within a relative tolerance of the f32
-# minimum, and re-evaluates only the shortlist in exact f64 with the
+# (min(a+b) >= min(a) + min(b)). Per job, the exact cost U of one alive
+# column (the previous job's pick) bounds the winner from above, so
+# every tier whose bound exceeds U is ruled out; the tiers left enter
+# one gathered pass over their columns, laid out tier by tier
+# (``TierPack.perm``). The pass evaluates a cheap f32 score, shortlists
+# within each tier everything within a relative tolerance of the tier's
+# f32 minimum, and re-evaluates only the shortlist in exact f64 with the
 # scalar op order — decisions and costs stay bit-identical to the flat
 # dense argmin (replay_on_pack / batched_cost_matrix+batched_argmin).
 # ---------------------------------------------------------------------------
@@ -603,6 +605,7 @@ def commit_placement(
 # magnitude window (or with negative inputs, see _f32_gate) fall back
 # to exact evaluation of the whole tier.
 _F32_SHORTLIST_RTOL = 1e-5
+_F32_SHORTLIST_SCALE = np.float64(1.0 + _F32_SHORTLIST_RTOL)  # f64 threshold
 _F32_SHORTLIST_MIN = 1e-30
 _F32_SHORTLIST_MAX = 1e30
 # Nudge finite tier bounds down by a relative ulp-scale guard so f64
@@ -636,6 +639,13 @@ class TierPack:
     labels: list[str]          # tier label per tier index
     tier_of: np.ndarray        # (S,) int64 tier index per pack column
     members: list[np.ndarray]  # per-tier ascending column indices
+    # Tier-contiguous column order (membership only, so ``refresh``
+    # leaves it alone): tier 0's members, then tier 1's, ...
+    perm: np.ndarray           # (S,) pack column at each position
+    pos_of: np.ndarray         # (S,) position of each pack column
+    starts: np.ndarray         # (T,) first position of each tier
+    sizes: np.ndarray          # (T,) member count of each tier
+    tier_p: np.ndarray         # (S,) tier index at each position
     net64: np.ndarray          # (S,) float64 network term, unpoisoned
     eff64: np.ndarray          # (S,) float64 effective bandwidth
     net32: np.ndarray          # (S,) float32 copies for the shortlist score
@@ -682,10 +692,19 @@ class TierPack:
             tier_of[i] = t
             groups[t].append(i)
         S, T = len(names), len(labels)
+        perm = np.argsort(tier_of, kind="stable")
+        pos_of = np.empty(S, np.int64)
+        pos_of[perm] = np.arange(S)
+        sizes = np.asarray([len(g) for g in groups], np.int64)
         tp = cls(
             labels=labels,
             tier_of=tier_of,
             members=[np.asarray(g, np.int64) for g in groups],
+            perm=perm,
+            pos_of=pos_of,
+            starts=np.cumsum(sizes) - sizes,
+            sizes=sizes,
+            tier_p=tier_of[perm],
             net64=np.empty(S, np.float64),
             eff64=np.empty(S, np.float64),
             net32=np.empty(S, np.float32),
@@ -740,7 +759,7 @@ class TierPack:
 
     def comp_tier_min(self, comp: np.ndarray) -> np.ndarray:
         """Per-tier minimum of a per-site computation column."""
-        return np.asarray([comp[mem].min() for mem in self.members], np.float64)
+        return np.minimum.reduceat(comp[self.perm], self.starts)
 
 
 def _f32_gate(jp: JobPack, sp: SitePack, tp: TierPack, weights: CostWeights) -> bool:
@@ -767,112 +786,171 @@ def _f32_gate(jp: JobPack, sp: SitePack, tp: TierPack, weights: CostWeights) -> 
     )
 
 
+class _RegionView:
+    """One call's per-column planes in ``TierPack.perm`` order, so a
+    job's pass over the tiers that enter reads whole arrays (every tier
+    entering) or one gather (some). Rows are net, eff, cap and the
+    job-independent computation term, in f64 and, for the shortlist
+    score, f32 (``v32`` is None when the f32 gate is off)."""
+
+    __slots__ = ("v64", "v32", "dead", "pos_of")
+
+    def __init__(self, sp: SitePack, tp: TierPack, comp_base: np.ndarray, use32: bool):
+        perm = tp.perm
+        comp_p = comp_base[perm]
+        self.v64 = np.stack([tp.net64[perm], tp.eff64[perm], sp.cap[perm], comp_p])
+        self.v32 = None
+        if use32:
+            self.v32 = np.stack(
+                [tp.net32[perm], tp.eff32[perm], tp.cap32[perm], comp_p.astype(np.float32)]
+            )
+        dead = ~sp.alive[perm]
+        self.dead = dead if dead.any() else None
+        self.pos_of = tp.pos_of
+
+    def set_comp(self, col: int, value: float) -> None:
+        """Column ``col``'s computation term moved (replay feedback)."""
+        k = self.pos_of[col]
+        self.v64[3, k] = value
+        if self.v32 is not None:
+            self.v32[3, k] = value
+
+
+def _tier_bounds(
+    tp: TierPack, cls: JobClass, bytes_j: float, work_j: float, comp_min: np.ndarray
+) -> np.ndarray:
+    """Per-tier admissible lower bound on one job's §IV cost, guarded
+    against f64 rounding; NaN bounds become -inf (never ruled out)."""
+    comp_lb = None
+    if cls is not JobClass.DATA:
+        comp_lb = comp_min + work_j / (tp.cap_max if work_j >= 0.0 else tp.cap_min)
+    dtc_lb = None
+    if cls is not JobClass.COMPUTE:
+        # 0/eff is 0 for every finite eff; the shortcut dodges the 0/0
+        # NaN an all-zero-bandwidth tier would inject.
+        dtc_lb = 0.0 if bytes_j == 0.0 else bytes_j / (
+            tp.eff_max if bytes_j > 0.0 else tp.eff_min
+        )
+    bound = class_total(cls, tp.net_min, comp_lb, dtc_lb)
+    fin = np.isfinite(bound)
+    if np.count_nonzero(fin) == fin.size:
+        bound -= np.abs(bound) * _BOUND_GUARD_RTOL
+    else:
+        bound[np.isnan(bound)] = -np.inf
+        fin = np.isfinite(bound)
+        bound[fin] -= np.abs(bound[fin]) * _BOUND_GUARD_RTOL
+    return bound
+
+
 def _hier_argmin_row(
     tp: TierPack,
-    sp: SitePack,
+    rv: _RegionView,
     cls: JobClass,
     bytes_j: float,
     work_j: float,
-    comp_base: np.ndarray,
     comp_min: np.ndarray,
-    use32: bool,
+    hint: int,
 ) -> tuple[int, float, int, int]:
     """One job's two-level argmin: ``(column, cost)`` bit-identical to
     ``argmin_finite`` over the flat dense row, or ``(-1, inf)`` when no
-    alive/finite column exists, followed by the number of tiers refined
-    and of columns evaluated in f64 on the way.
+    alive/finite column exists, followed by the number of tiers that
+    entered the pass and of columns evaluated in f64.
 
-    ``comp_base`` is the job-independent computation column (the full
-    per-job term is ``comp_base + work_j / cap``); ``comp_min`` its
-    per-tier minimum, maintained by the caller.
+    ``comp_min`` is the per-tier minimum of the computation column the
+    caller keeps in ``rv``; ``hint`` is a column whose exact cost bounds
+    the winner from above (-1 for none). Call under ``np.errstate``
+    ignoring divide, invalid and over.
     """
-    has_comp = cls is not JobClass.DATA
-    has_dtc = cls is not JobClass.COMPUTE
-    comp_lb = None
-    if has_comp:
-        if work_j >= 0.0:
-            wterm = work_j / tp.cap_max
-        else:
-            wterm = work_j / tp.cap_min
-        comp_lb = comp_min + wterm
-    dtc_lb = None
-    if has_dtc:
-        if bytes_j == 0.0:
-            # 0/eff is 0 for every finite eff; the shortcut dodges the
-            # 0/0 NaN an all-zero-bandwidth tier would inject.
-            dtc_lb = np.zeros(len(tp.labels))
-        else:
-            with np.errstate(divide="ignore", invalid="ignore"):
-                dtc_lb = bytes_j / (tp.eff_max if bytes_j > 0.0 else tp.eff_min)
-    bound = np.asarray(class_total(cls, tp.net_min, comp_lb, dtc_lb), np.float64)
-    # NaN bounds (degenerate link values) carry no pruning information:
-    # force them to -inf so the tier is always refined, never skipped.
-    bad = np.isnan(bound)
-    if bad.any():
-        bound[bad] = -np.inf
-    fin = np.isfinite(bound)
-    bound[fin] -= np.abs(bound[fin]) * _BOUND_GUARD_RTOL
+    T = len(tp.labels)
+    upper = np.inf
+    if hint >= 0:
+        k = rv.pos_of[hint]
+        if rv.dead is None or not rv.dead[k]:
+            # The hint's exact cost on Python floats (IEEE f64, the dense
+            # row's value); a zero divisor leaves it undefined.
+            net, eff, cap, comp = rv.v64[:, k].tolist()
+            try:
+                upper = class_total(
+                    cls, net,
+                    None if cls is JobClass.DATA else comp + work_j / cap,
+                    None if cls is JobClass.COMPUTE else bytes_j / eff,
+                )
+            except ZeroDivisionError:
+                pass
+    n_in = T
+    if math.isfinite(upper):
+        # <= (not <): a tier whose bound ties U may hold an equal-cost
+        # column with a *lower* index, which the flat argmin would pick.
+        enter = _tier_bounds(tp, cls, bytes_j, work_j, comp_min) <= upper
+        n_in = int(np.count_nonzero(enter))
+    if not n_in:
+        return -1, np.inf, 0, 0
+    pos = None  # positions (in perm order) of the columns in the pass
+    v32, dead = rv.v32, rv.dead
+    starts, sizes = tp.starts, tp.sizes
+    if n_in < T:
+        pos = enter[tp.tier_p].nonzero()[0]
+        sizes = sizes[enter]
+        starts = sizes.cumsum() - sizes
+        if v32 is not None:
+            v32 = v32.take(pos, axis=1)
+        if dead is not None:
+            dead = dead[pos]
 
-    best_cost = np.inf
-    best_col = -1
-    tiers = cols_refined = 0
-    for t in np.argsort(bound, kind="stable"):
-        t = int(t)
-        # <= (not <): a runner-up tier whose bound ties the refined best
-        # may hold an equal-cost column with a *lower* index, and the
-        # flat argmin's first-index tie-break would pick it.
-        if bound[t] > best_cost:
-            break
-        cols = tp.members[t]
-        short = cols
-        if use32:
-            with np.errstate(divide="ignore", invalid="ignore", over="ignore"):
-                if cls is JobClass.DATA:
-                    score = (np.float32(bytes_j) / tp.eff32[cols]) + tp.net32[cols]
-                else:
-                    comp32 = comp_base[cols].astype(np.float32) + np.float32(
-                        work_j
-                    ) / tp.cap32[cols]
-                    if cls is JobClass.COMPUTE:
-                        score = comp32 + tp.net32[cols]
-                    else:
-                        score = (tp.net32[cols] + comp32) + (
-                            np.float32(bytes_j) / tp.eff32[cols]
-                        )
-            dead32 = ~sp.alive[cols]
-            if dead32.any():
-                score[dead32] = np.inf
-            m32 = float(score.min())
-            if _F32_SHORTLIST_MIN < m32 < _F32_SHORTLIST_MAX:
-                short = cols[score <= m32 * (1.0 + _F32_SHORTLIST_RTOL)]
-        # Exact f64 refinement on the shortlist: elementwise ops on
-        # column slices equal the sliced full-vector results, so these
-        # values match the flat dense row bit for bit.
-        tiers += 1
-        cols_refined += len(short)
-        comp_s = None
-        if has_comp:
-            comp_s = comp_base[short] + work_j / sp.cap[short]
-        dtc_s = None
-        if has_dtc:
-            with np.errstate(divide="ignore", invalid="ignore"):
-                dtc_s = bytes_j / tp.eff64[short]
-        row = np.asarray(class_total(cls, tp.net64[short], comp_s, dtc_s), np.float64)
-        deads = ~sp.alive[short]
-        if deads.any():
-            row[deads] = np.inf
-        k = int(np.argmin(row))
-        c = float(row[k])
-        if np.isfinite(c):
-            col = int(short[k])
-            if c < best_cost or (c == best_cost and col < best_col):
-                best_cost, best_col = c, col
-    return best_col, best_cost, tiers, cols_refined
+    if v32 is not None:
+        net32, eff32, cap32, comp32 = v32[0], v32[1], v32[2], v32[3]
+        if cls is JobClass.DATA:
+            score = (np.float32(bytes_j) / eff32) + net32
+        else:
+            comp32 = comp32 + np.float32(work_j) / cap32
+            if cls is JobClass.COMPUTE:
+                score = comp32 + net32
+            else:
+                score = (net32 + comp32) + (np.float32(bytes_j) / eff32)
+        if dead is not None:
+            score[dead] = np.inf
+        m32 = np.minimum.reduceat(score, starts)
+        thr = m32 * _F32_SHORTLIST_SCALE
+        # argmin/argmax find NaN first, so a NaN fails the window too.
+        lo, hi = m32[m32.argmin()], m32[m32.argmax()]
+        if not (_F32_SHORTLIST_MIN < lo and hi < _F32_SHORTLIST_MAX):
+            # Outside the sane window (or NaN) the tier is refined whole.
+            thr[~((m32 > _F32_SHORTLIST_MIN) & (m32 < _F32_SHORTLIST_MAX))] = np.inf
+        short = (score <= thr.repeat(sizes)).nonzero()[0]
+        if dead is not None:
+            dead = dead[short]
+        pos = short if pos is None else pos[short]
+
+    # Exact f64 refinement: elementwise ops on gathered columns equal
+    # the full-vector results, so these values match the flat dense row
+    # bit for bit.
+    v64 = rv.v64 if pos is None else rv.v64.take(pos, axis=1)
+    net, eff, cap, comp = v64[0], v64[1], v64[2], v64[3]
+    comp_s = None if cls is JobClass.DATA else comp + work_j / cap
+    dtc_s = None if cls is JobClass.COMPUTE else bytes_j / eff
+    row = class_total(cls, net, comp_s, dtc_s)
+    if dead is not None:
+        row[dead] = np.inf
+    k = int(row.argmin())
+    c = float(row[k])
+    if not math.isfinite(c):
+        return -1, np.inf, n_in, row.size
+    # The pass runs in perm order, not index order: among equal minima
+    # the lowest pack column wins, as in the flat argmin.
+    if row.size > 1 and np.count_nonzero(row == c) > 1:
+        ties = (row == c).nonzero()[0]
+        col = int(tp.perm[ties if pos is None else pos[ties]].min())
+    else:
+        col = int(tp.perm[k if pos is None else pos[k]])
+    return col, c, n_in, row.size
 
 
 def _count_refined(tiers: int, cols: int) -> None:
     trace.count("diana.hier.tiers_refined", tiers)
     trace.count("diana.hier.cols_refined", cols)
+
+
+_ROW_ERRSTATE = dict(divide="ignore", invalid="ignore", over="ignore")
 
 
 def hier_select(
@@ -887,23 +965,25 @@ def hier_select(
     materializing the (J, S) plane."""
     comp_site = comp_site_column(sp, weights)
     comp_min = tp.comp_tier_min(comp_site)
-    use32 = _f32_gate(jp, sp, tp, weights)
+    rv = _RegionView(sp, tp, comp_site, _f32_gate(jp, sp, tp, weights))
     J = len(jp.classes)
     idx = np.empty(J, np.int64)
     costs = np.empty(J, np.float64)
     tiers = cols = 0
-    for j in range(J):
-        col, c, nt, nc = _hier_argmin_row(
-            tp, sp, jp.classes[j],
-            float(jp.bytes_[j]), float(jp.work[j]),
-            comp_site, comp_min, use32,
-        )
-        if col < 0:
-            raise RuntimeError("no alive site available")
-        idx[j] = col
-        costs[j] = c
-        tiers += nt
-        cols += nc
+    col = -1
+    with np.errstate(**_ROW_ERRSTATE):
+        for j in range(J):
+            col, c, nt, nc = _hier_argmin_row(
+                tp, rv, jp.classes[j],
+                float(jp.bytes_[j]), float(jp.work[j]),
+                comp_min, col,
+            )
+            if col < 0:
+                raise RuntimeError("no alive site available")
+            idx[j] = col
+            costs[j] = c
+            tiers += nt
+            cols += nc
     _count_refined(tiers, cols)
     return BatchPlacement(
         site_indices=idx,
@@ -926,8 +1006,8 @@ def hier_replay(
     with trace.span("diana.plane"):
         comp_base = comp_site_column(sp, weights).copy()
         comp_min = tp.comp_tier_min(comp_base)
-        use32 = _f32_gate(jp, sp, tp, weights)
-    with trace.span("diana.replay"):
+        rv = _RegionView(sp, tp, comp_base, _f32_gate(jp, sp, tp, weights))
+    with trace.span("diana.replay"), np.errstate(**_ROW_ERRSTATE):
         q = sp.queue.copy()
         w = sp.work.copy()
         wq, ww = weights.w_queue, weights.w_work
@@ -937,11 +1017,12 @@ def hier_replay(
         site_idx = np.empty(J, np.int64)
         costs = np.empty(J, np.float64)
         tiers = cols = 0
+        col = -1
         for j in range(J):
             col, c, nt, nc = _hier_argmin_row(
-                tp, sp, jp.classes[j],
+                tp, rv, jp.classes[j],
                 float(jp.bytes_[j]), float(jp.work[j]),
-                comp_base, comp_min, use32,
+                comp_min, col,
             )
             if col < 0:
                 raise RuntimeError("no alive site available")
@@ -956,6 +1037,7 @@ def hier_replay(
             # Same elementwise expression as comp_site_column so the value
             # stays bit-identical to a full recomputation (replay_on_pack).
             comp_base[s] = (wq * q[s] / cap[s] + ww * w[s] / cap[s]) + load_term[s]
+            rv.set_comp(s, comp_base[s])
             t = int(tp.tier_of[s])
             if comp_base[s] < comp_min[t]:
                 comp_min[t] = comp_base[s]
