@@ -29,6 +29,12 @@ view. This module is that layer:
   representatives (the RootGrid tier of Fig 5) — message count scales
   with tier sizes, not S².
 
+On a reliable transport with a delay the delta wire is simulated a round
+at a time: a round's packets, and later their acks, are one heap entry
+each, applied as arrays over the peers' stacked views, with the effects
+and in the order of the per-packet path (which the faulty transport,
+zero latency, tier summaries and the full wire keep).
+
 Delivery latency models the WAN: adverts sent at t arrive at
 t+latency, so a receiver's ``staleness`` of a remote row is
 (now − stamp) — the knob Q4 migration uses to decide which peers it
@@ -67,6 +73,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from . import trace
 from .batch import (
     PACK_FIELDS,
     JobPack,
@@ -416,10 +423,12 @@ class PeerScheduler:
     protocol's initial full-state exchange); afterwards only the home
     columns are ever read from authoritative state
     (``refresh_dynamic(only=home)``) — every remote column changes
-    exclusively through ``receive``-d adverts. ``home_sites`` lets one
-    peer own a partition of sites (the simulator runs N peers over S >
-    N sites); the default is the single ``home`` site of the paper's
-    one-scheduler-per-site deployment.
+    exclusively through ``receive``-d adverts. ``home_sites`` is the
+    part of the grid this peer owns, ``home`` among them: in the
+    simulator, the partition the deployment states (e.g. a RootGrid
+    region, homed at its Tier-0 or Tier-1; ``SimConfig.peer_sites``)
+    or, without one, a round-robin share. The default is the single
+    ``home`` site of the paper's one-scheduler-per-site deployment.
     """
 
     def __init__(
@@ -490,16 +499,22 @@ class PeerScheduler:
         # Remote RootGrid aggregates received via tier-summary gossip
         # (tier label → freshest TierSummary, last-writer-wins by stamp).
         self.tier_summaries: dict[str, TierSummary] = {}
+        # With tracking on: home sites this peer's own placements wrote
+        # (``_commit_home``) since the last stamped refresh, which
+        # re-measures them; and whether any home content was re-read or
+        # handed over since then (if not, no epoch can open).
+        self._home_written: set = set()
+        self._home_moved = True
 
     # -- incremental home refresh ---------------------------------------------
     def enable_home_dirty_tracking(self) -> None:
-        """Opt in to narrowed content refreshes: after this, a
-        provider-backed ``refresh_home(now=None)`` re-measures only the
-        home sites the authority reported dirty via
-        ``mark_home_dirty`` (all of them initially). The authority must
-        then report *every* home-state mutation, or the view goes
-        stale; stamped refreshes (``now=...``, the exchange round path)
-        always re-measure the full partition."""
+        """Opt in to narrowed refreshes: after this, a provider-backed
+        ``refresh_home`` re-measures only the home sites the authority
+        reported dirty via ``mark_home_dirty`` (all of them initially)
+        and, when stamped (``now=...``, the exchange round path), those
+        this peer's own placements wrote since the last stamped refresh.
+        The authority must then report *every* home-state mutation, or
+        the view goes stale."""
         self._home_dirty = set(self.home_names)
 
     def mark_home_dirty(self, name: str) -> None:
@@ -536,38 +551,56 @@ class PeerScheduler:
         ``staleness()`` and wrongly distrust a fresh peer). ``states``
         swaps in fresh authoritative snapshots first (the simulator
         regenerates ``SiteState`` objects per measurement)."""
-        pulled_all = False
         if states is None and self.state_provider is not None:
-            if now is None and self._home_dirty is not None:
-                # Narrowed content-only refresh: re-measure just the
-                # home sites the authority reported dirty. Unchanged
-                # columns would re-read to identical floats, so the
-                # narrowing is bit-identical to a full refresh.
-                if not self._home_dirty:
+            if self._home_dirty is not None:
+                if not (self._home_dirty or self._home_moved or self._home_written):
+                    # Nothing re-read since the last stamp: no epoch opens.
+                    if now is not None:
+                        self.stamp[self.home_cols] = now
                     return
-                names = [n for n in self.home_names if n in self._home_dirty]
-                for n in names:
-                    self.authoritative[n] = self.state_provider(n)
-                self.view.refresh_dynamic(self.authoritative, only=names)
-                for n in names:
-                    self.free[self._col[n]] = self.authoritative[n].free_slots
-                self._home_dirty.clear()
+                # Narrowed refresh: re-measure just the home sites the
+                # authority reported dirty (stamped: and those this
+                # peer's placements wrote). Unchanged columns would
+                # re-read to identical floats, so the narrowing is
+                # bit-identical to a full refresh.
+                pull = self._home_dirty
+                if now is not None and self._home_written:
+                    pull = pull | self._home_written
+                if pull:
+                    names = [n for n in self.home_names if n in pull]
+                    for n in names:
+                        self.authoritative[n] = self.state_provider(n)
+                    self.view.refresh_dynamic(self.authoritative, only=names)
+                    for n in names:
+                        self.free[self._col[n]] = self.authoritative[n].free_slots
+                    self._home_dirty.clear()
+                    self._home_moved = True
+                if now is not None:
+                    self._home_written.clear()
+                    self._stamp_home(now)
                 return
             states = {n: self.state_provider(n) for n in self.home_names}
-            pulled_all = True
         if states is not None:
             for n, st in states.items():
                 if n not in self.home_sites:
                     raise KeyError(f"{n!r} is not a home site of peer {self.home!r}")
                 self.authoritative[n] = st
         self.view.refresh_dynamic(self.authoritative, only=self.home_names)
-        cols = np.flatnonzero(self.home_cols)
-        for c in cols:
+        for c in np.flatnonzero(self.home_cols):
             self.free[c] = self.authoritative[self.view.names[c]].free_slots
-        if pulled_all and self._home_dirty is not None:
-            self._home_dirty.clear()
-        if now is None:
+        self._home_moved = True
+        if now is not None:
+            self._stamp_home(now)
+
+    def _stamp_home(self, now: float) -> None:
+        """Stamp every home column ``now``, opening a new epoch where the
+        content differs from the published snapshot (none can when no
+        home content was re-read since the last stamp)."""
+        self.stamp[self.home_cols] = now
+        if not self._home_moved:
             return
+        self._home_moved = False
+        cols = np.flatnonzero(self.home_cols)
         cur = np.stack([
             self.view.queue[cols], self.view.work[cols], self.view.load[cols],
             self.free[cols], self.view.alive[cols].astype(np.float64),
@@ -575,7 +608,6 @@ class PeerScheduler:
         changed = cols[np.any(cur != self._pub[:, cols], axis=0)]
         self.version[changed] += 1
         self._pub[:, cols] = cur
-        self.stamp[cols] = now
 
     def staleness(self, now: float) -> np.ndarray:
         """Seconds since each column's row was measured by its owner;
@@ -617,11 +649,11 @@ class PeerScheduler:
             del self.authoritative[n]
         self.home_names = [n for n in self.home_names if n not in gone]
         self.home_sites = frozenset(self.home_names)
-        self.home_cols = np.asarray(
-            [n in self.home_sites for n in self.view.names]
-        )
+        self.home_cols[:] = [n in self.home_sites for n in self.view.names]
         if self._home_dirty is not None:
             self._home_dirty -= gone
+        self._home_written -= gone
+        self._home_moved = True
         return grant
 
     def adopt(self, grant: dict) -> None:
@@ -651,14 +683,13 @@ class PeerScheduler:
             if n not in self.home_sites:
                 self.home_names.append(n)
         self.home_sites = frozenset(self.home_names)
-        self.home_cols = np.asarray(
-            [n in self.home_sites for n in self.view.names]
-        )
+        self.home_cols[:] = [n in self.home_sites for n in self.view.names]
         self.view.refresh_dynamic(self.authoritative, only=names)
         for n in names:
             self.free[self._col[n]] = self.authoritative[n].free_slots
         if self._home_dirty is not None:
             self._home_dirty.update(names)
+        self._home_moved = True
 
     # -- gossip/epoch advertisement --------------------------------------------
     def adverts(self, cols: Optional[Sequence[int]] = None) -> list[SiteAdvert]:
@@ -932,6 +963,9 @@ class PeerScheduler:
             st = self.authoritative[self.view.names[c]]
             st.queue_length = float(self.view.queue[c])
             st.waiting_work = float(self.view.work[c])
+        self._home_moved = True
+        if self._home_dirty is not None:
+            self._home_written.update(self.home_names)
 
     # -- §VIII bulk groups over the world view ---------------------------------
     def view_states(self) -> dict[str, SiteState]:
@@ -996,9 +1030,50 @@ def single_peer(
     )
 
 
-@dataclass
+_NONE = np.zeros(0, np.int64)
+
+
+class _PairStore:
+    """The wire state of ``rows`` directed pairs, one row each, as
+    arrays (see ``_PairState`` for the fields): a batched round reads
+    and writes every pair at once."""
+
+    def __init__(self, rows: int, S: int):
+        self.acked = np.full((rows, S), -1, np.int64)
+        self.hb_stamp = np.full((rows, S), -np.inf)
+        self.send_seq = np.zeros(rows, np.int64)
+        self.recv_max = np.full(rows, -1, np.int64)
+        self.recv_window = np.zeros(rows, np.uint64)
+        self.sync_round = np.full(rows, -1, np.int64)  # -1 = None
+        self.table: list = [None] * rows
+
+    def reset(self, k: int) -> None:
+        self.acked[k] = -1
+        self.hb_stamp[k] = -np.inf
+        self.send_seq[k] = 0
+        self.recv_max[k] = -1
+        self.recv_window[k] = 0
+        self.sync_round[k] = -1
+        self.table[k] = None
+
+
+class _StoreInt:
+    """A ``_PairState`` integer field: its row of the store's array of
+    the same name."""
+
+    def __set_name__(self, owner, name: str) -> None:
+        self.name = name
+
+    def __get__(self, st, owner=None) -> int:
+        return int(getattr(st._s, self.name)[st._k])
+
+    def __set__(self, st, value: int) -> None:
+        getattr(st._s, self.name)[st._k] = value
+
+
 class _PairState:
-    """Per-directed-(sender → receiver) wire state.
+    """Per-directed-(sender → receiver) wire state: row ``k`` of a
+    ``_PairStore`` (its own one-row store by default).
 
     ``acked`` and ``hb_stamp`` live at the sender end (what the
     receiver last acknowledged / the stamp last shipped per column);
@@ -1016,38 +1091,128 @@ class _PairState:
     reordering is detected without unbounded memory.
     """
 
-    acked: Optional[np.ndarray] = None      # (S,) int64, -1 = never acked
-    hb_stamp: Optional[np.ndarray] = None   # (S,) f64 stamp last sent
-    table: Optional[list] = None
-    sync_round: Optional[int] = None
-    send_seq: int = 0
-    recv_max: int = -1
-    recv_window: int = 0
+    __slots__ = ("_s", "_k")
+
+    def __init__(self, store: Optional[_PairStore] = None, k: int = 0):
+        self._s = _PairStore(1, 0) if store is None else store
+        self._k = k
+
+    @property
+    def acked(self) -> np.ndarray:          # (S,) int64, -1 = never acked
+        return self._s.acked[self._k]
+
+    @property
+    def hb_stamp(self) -> np.ndarray:       # (S,) f64 stamp last sent
+        return self._s.hb_stamp[self._k]
+
+    @property
+    def table(self) -> Optional[list]:
+        return self._s.table[self._k]
+
+    @table.setter
+    def table(self, value: Optional[list]) -> None:
+        self._s.table[self._k] = value
+
+    @property
+    def sync_round(self) -> Optional[int]:
+        r = int(self._s.sync_round[self._k])
+        return None if r < 0 else r
+
+    @sync_round.setter
+    def sync_round(self, value: Optional[int]) -> None:
+        self._s.sync_round[self._k] = -1 if value is None else value
+
+    send_seq = _StoreInt()
+    recv_max = _StoreInt()
+    recv_window = _StoreInt()
 
     def accept_seq(self, s: int) -> tuple[bool, bool]:
         """Advance the replay window with pair seq ``s``. Returns
         ``(fresh, reordered)``: not-fresh means duplicate (or older
         than the 64-seq window — indistinguishable, treated the same);
         reordered means fresh but behind an already-seen packet."""
-        if s > self.recv_max:
-            shift = s - self.recv_max
+        recv_max = self.recv_max
+        if s > recv_max:
+            shift = s - recv_max
             self.recv_window = (
                 ((self.recv_window << shift) | (1 << (shift - 1)))
                 & 0xFFFFFFFFFFFFFFFF
-                if self.recv_max >= 0 else 0
+                if recv_max >= 0 else 0
             )
             self.recv_max = s
             return True, False
-        if s == self.recv_max:
+        if s == recv_max:
             return False, False  # window bits cover seqs BELOW the max
-        behind = self.recv_max - 1 - s
+        behind = recv_max - 1 - s
         if behind >= 64:
             return False, False
         bit = 1 << behind
-        if self.recv_window & bit:
+        window = self.recv_window
+        if window & bit:
             return False, False
-        self.recv_window |= bit
+        self.recv_window = window | bit
         return True, True
+
+
+@dataclass
+class _RoundPlan:
+    """A batched round's directed pairs in send order (sender-major)
+    with their sender, receiver and pair-store row, and the (pair,
+    column) entries a packet that is not a full sync can carry: the
+    sender's columns the receiver does not hear owner-direct, with
+    their flat indices into the (N, S) peer arrays (``ic``: the
+    sender's column, ``at``: the receiver's) and the pair store
+    (``kc``). ``unique``: no (receiver, column) is among them twice, so
+    no packet of a round sees another's effect; ``home_only``: every
+    entry is a column its sender owns. ``pm`` is the (N, N) mask of the
+    pairs; ``next_full`` the first round at which one of them is due a
+    full sync. ``key`` counts the leaves and joins it was built after."""
+
+    key: int
+    pairs: list
+    I: np.ndarray
+    J: np.ndarray
+    K: np.ndarray
+    r: np.ndarray
+    c: np.ndarray
+    ic: np.ndarray
+    kc: np.ndarray
+    at: np.ndarray
+    unique: bool
+    home_only: bool
+    pm: np.ndarray
+    next_full: int = 0
+
+
+@dataclass
+class _RoundBatch:
+    """The packets of one batched round in flight: its plan, which
+    pairs sent a full sync, their pair seqs and the leaves and joins
+    before the send (``rev``). A round where every pair full-syncs
+    keeps ``dense``: each packet's column mask and the senders' epochs,
+    stamps and dequantized rows (queue, work, load, free slots,
+    liveness). Any other round keeps the entries it shipped:
+    advertised (``d_*``: pair row, column, epoch, stamp, and the
+    dequantized values) and heartbeats (``h_*``: pair row, column,
+    epoch echo, stamp). ``steady``: a heartbeat round replayed from the
+    plan (``_send_steady``)."""
+
+    plan: _RoundPlan
+    full: np.ndarray
+    pair_seqs: np.ndarray
+    rev: int
+    unique: bool = False
+    steady: bool = False
+    dense: Optional[tuple] = None
+    d_r: np.ndarray = field(default_factory=lambda: _NONE)
+    d_c: np.ndarray = field(default_factory=lambda: _NONE)
+    d_v: np.ndarray = field(default_factory=lambda: _NONE)
+    d_t: np.ndarray = field(default_factory=lambda: _NONE)
+    d_content: Optional[tuple] = None
+    h_r: np.ndarray = field(default_factory=lambda: _NONE)
+    h_c: np.ndarray = field(default_factory=lambda: _NONE)
+    h_v: np.ndarray = field(default_factory=lambda: _NONE)
+    h_t: np.ndarray = field(default_factory=lambda: _NONE)
 
 
 class _FailureDetector:
@@ -1227,8 +1392,11 @@ class GossipExchange:
         # Heap entries: (due, tiebreak, receiver, kind, payload) with
         # kind "adverts" (full wire: (sender, advert list)), "packet"
         # (delta wire: (sender, packet seq, bytes)), "ack" (delta
-        # wire: the acked packet's seq) or "rto" (retransmit timer at
-        # sender ``receiver``: (target, packet seq, attempt, interval)).
+        # wire: the acked packet's seq), "rto" (retransmit timer at
+        # sender ``receiver``: (target, packet seq, attempt, interval)),
+        # or, for a batched round, "batch" (the round's packets: a
+        # ``_RoundBatch``) and "batch_ack" (the batch and the indices of
+        # its packets being acknowledged).
         self._in_flight: list[tuple[float, int, int, str, object]] = []
         # Delta wire: packets sent but not yet acknowledged, seq →
         # ((sender, receiver), advertised cols, their versions, the
@@ -1237,12 +1405,57 @@ class GossipExchange:
             int, tuple[tuple[int, int], np.ndarray, np.ndarray, bytes]
         ] = {}
         self._pairs: dict[tuple[int, int], _PairState] = {}
+        # When the peers share one column order, every pair's wire state
+        # is row i*N + j (sender i → receiver j) of one store.
+        names0 = list(self.peers[0].view.names) if self.peers else []
+        shared = all(list(p.view.names) == names0 for p in self.peers)
+        N, S = len(self.peers), len(names0)
+        self._store = _PairStore(N * N, S) if shared else None
+        # The reliable delta wire with a delay is simulated a round at a
+        # time (``_round_batched``): a round's packets, and later their
+        # acks, are one heap entry each, applied as arrays with the
+        # effects, in the order, of the per-packet path. The peers'
+        # epoch, stamp, speculation and home vectors then become rows
+        # of the exchange's (N, S) arrays (a peer serves one exchange),
+        # and so do its view's advertised fields and free slots.
+        self._batched = (
+            shared and wire == "delta" and transport is None
+            and not summaries and self.latency_s > 0.0
+        )
+        if self._batched:
+            peers = self.peers
+            self._V = np.stack([p.version for p in peers])
+            self._T = np.stack([p.stamp for p in peers])
+            self._D = np.stack([p._dirty for p in peers])
+            self._HC = np.stack([p.home_cols for p in peers])
+            self._F = np.stack([p.free for p in peers])
+            self._Q = np.stack([p.view.queue for p in peers])
+            self._W = np.stack([p.view.work for p in peers])
+            self._L = np.stack([p.view.load for p in peers])
+            self._A = np.stack([p.view.alive for p in peers])
+            for k, p in enumerate(peers):
+                p.version, p.stamp = self._V[k], self._T[k]
+                p._dirty, p.home_cols, p.free = self._D[k], self._HC[k], self._F[k]
+                p.view.queue, p.view.work = self._Q[k], self._W[k]
+                p.view.load, p.view.alive = self._L[k], self._A[k]
+        # The peer of every leave or join (set_active), in order: a
+        # batched round records how many came before it.
+        self._churn: list[int] = []
+        self._plan: Optional[_RoundPlan] = None
+        # Steady state of the batched wire, checked before each use:
+        # the epochs at the last round that advertised nothing (and
+        # their values at the plan's entries), and the epochs,
+        # speculation and refreshed entries of the last heartbeat
+        # round delivered (see _send_steady, _deliver_batch).
+        self._quiet: Optional[tuple] = None
+        self._steady: Optional[tuple] = None
+        self._table_b: Optional[int] = None  # site-id table bytes
         self._groups = self._tier_groups()
         self._reps = [g[0] for g in self._groups]
         self._group_of = {
             i: gi for gi, g in enumerate(self._groups) for i in g
         }
-        self._owner_suppress = self._owner_suppression_masks()
+        self._set_owner_suppression()
         # Tier-summary gossip: cross-tier sends carry one aggregate row
         # per tier instead of dense per-site rows (an at-scale
         # approximation — remote tiers' dense rows stop refreshing).
@@ -1314,11 +1527,23 @@ class GossipExchange:
         if self._active[idx] == bool(active):
             return
         self._active[idx] = bool(active)
+        self._churn.append(idx)
         for key in [k for k in self._pairs if idx in k]:
             del self._pairs[key]
         for seq in [s for s, e in self._pending.items() if idx in e[0]]:
             del self._pending[seq]
+        self._set_owner_suppression()
+
+    def _set_owner_suppression(self) -> None:
+        """(Re)build the owner-direct suppression masks, and their
+        (N*N, S) stack for the batched round (zero rows for pairs
+        without a mask)."""
         self._owner_suppress = self._owner_suppression_masks()
+        if self._store is not None:
+            N = len(self.peers)
+            self._supp_rows = np.zeros(self._store.acked.shape, bool)
+            for (i, j), m in self._owner_suppress.items():
+                self._supp_rows[i * N + j] = m
 
     def _owner_suppression_masks(self) -> dict[tuple[int, int], np.ndarray]:
         """Per directed pair (sender i → receiver j): the sender-column
@@ -1365,11 +1590,12 @@ class GossipExchange:
     def _pair(self, i: int, j: int) -> _PairState:
         st = self._pairs.get((i, j))
         if st is None:
-            S = len(self.peers[i].view.names)
-            st = _PairState(
-                acked=np.full(S, -1, np.int64),
-                hb_stamp=np.full(S, -np.inf),
-            )
+            if self._store is not None:
+                k = i * len(self.peers) + j
+                self._store.reset(k)
+                st = _PairState(self._store, k)
+            else:
+                st = _PairState(_PairStore(1, len(self.peers[i].view.names)))
             self._pairs[(i, j)] = st
         return st
 
@@ -1658,7 +1884,18 @@ class GossipExchange:
 
     @property
     def in_flight(self) -> int:
-        return len(self._in_flight)
+        """Messages in flight: a batched round's entry counts each of
+        its packets (or acks)."""
+        n = 0
+        for e in self._in_flight:
+            if e[3] == "batch":
+                n += len(e[4].plan.pairs)
+            elif e[3] == "batch_ack":
+                acked = e[4][1]
+                n += len(e[4][0].plan.pairs) if acked is None else int(acked.sum())
+            else:
+                n += 1
+        return n
 
     def next_due(self) -> float:
         """Arrival time of the earliest in-flight message (advert
@@ -1668,10 +1905,29 @@ class GossipExchange:
         return self._in_flight[0][0]
 
     # -- protocol --------------------------------------------------------------
+    def _marks(self) -> tuple[int, int, int]:
+        s = self.stats
+        return s.deliveries, s.bytes_sent, s.adverts_applied
+
+    def _count_since(self, marks: tuple[int, int, int]) -> None:
+        """Add what the stats gained since ``marks`` to the
+        ``diana.p2p.*`` counters."""
+        now = self._marks()
+        for name, a, b in zip(("packets", "bytes", "rows_merged"), marks, now):
+            trace.count(f"diana.p2p.{name}", b - a)
+
     def deliver_due(self, now: float) -> int:
         """Deliver every in-flight message whose latency elapsed.
         Returns the number of advert columns applied (acks deliver too
         but count nothing here)."""
+        with trace.span("diana.p2p.deliver"):
+            marks = self._marks() if trace.on else None
+            applied = self._deliver_due(now)
+            if marks is not None:
+                self._count_since(marks)
+        return applied
+
+    def _deliver_due(self, now: float) -> int:
         applied = 0
         while self._in_flight and self._in_flight[0][0] <= now:
             due, _tb, j, kind, payload = heapq.heappop(self._in_flight)
@@ -1702,6 +1958,10 @@ class GossipExchange:
                 applied += self._deliver_packet(due, sender, j, buf, pseq)
             elif kind == "rto":  # j is the retransmitting sender here
                 self._fire_rto(due, j, payload)
+            elif kind == "batch":
+                applied += self._deliver_batch(due, payload)
+            elif kind == "batch_ack":
+                self._ack_batch(*payload)
             else:  # "ack" — j is the original packet's sender here
                 if not self._active[j]:
                     continue
@@ -1716,10 +1976,22 @@ class GossipExchange:
         wire. Zero-latency sends apply immediately (so adverts cascade
         through the mesh within the round); otherwise they queue until
         ``deliver_due``."""
+        with trace.span("diana.p2p.round"):
+            marks = self._marks() if trace.on else None
+            self._round(now)
+            if marks is not None:
+                trace.count("diana.p2p.rounds")
+                self._count_since(marks)
+        return self.stats
+
+    def _round(self, now: float) -> None:
         self.stats.rounds += 1
         for k, p in enumerate(self.peers):
             if self._active[k]:
                 p.refresh_home(now)
+        if self._batched and now + self.latency_s > now:
+            self._round_batched(now)
+            return
         for i, p in enumerate(self.peers):
             targets = self.neighbors(i, self.stats.rounds)
             if not targets:
@@ -1753,7 +2025,6 @@ class GossipExchange:
                         summary_wire_bytes(s) for s in summary_rows
                     )
                     self._send_message(now, i, j, "summaries", summary_rows)
-        return self.stats
 
     def _summaries_payload(self, i: int, now: float) -> list[TierSummary]:
         """Sender ``i``'s summary rows: its own tier re-aggregated
@@ -1828,6 +2099,413 @@ class GossipExchange:
             # Packet not delivered+acked inline: arm its ack-timeout.
             self._schedule_rto(now, i, j, seq, 1, self._rto_initial())
 
+    def _round_plan(self, rnd: int) -> _RoundPlan:
+        """This round's ``_RoundPlan``, reused while no peer has left or
+        joined (without a fan-out cap the pairs do not rotate)."""
+        key = len(self._churn)
+        plan = self._plan
+        if plan is not None and plan.key == key and self.fanout is None:
+            return plan
+        N, S = len(self.peers), self._V.shape[1]
+        pairs = [(i, j) for i in range(N) for j in self.neighbors(i, rnd)]
+        for i, j in pairs:
+            self._pair(i, j)
+        I = np.fromiter((i for i, _ in pairs), np.int64, len(pairs))
+        J = np.fromiter((j for _, j in pairs), np.int64, len(pairs))
+        K = I * N + J
+        r, c = np.nonzero(~self._supp_rows[K])
+        ic, at = I[r] * S + c, J[r] * S + c
+        pm = np.zeros((N, N), bool)
+        pm[I, J] = True
+        self._plan = _RoundPlan(
+            key, pairs, I, J, K, r, c, ic, K[r] * S + c, at,
+            len(np.unique(at)) == len(at), bool(self._HC.ravel()[ic].all()), pm,
+        )
+        return self._plan
+
+    def _round_batched(self, now: float) -> None:
+        """``_send_delta`` for every pair of the round at once.
+
+        Each packet's advertised columns, heartbeats, full sync, wire
+        bytes and sequence numbers are what ``_send_delta`` gives; the
+        packets are not serialized (their sizes follow
+        ``encode_packet``'s layout) and travel as one heap entry that
+        holds what each carries."""
+        rnd = self.stats.rounds
+        plan = self._round_plan(rnd)
+        P = len(plan.pairs)
+        if not P:
+            return
+        st, K = self._store, plan.K
+        rev = len(self._churn)
+        if rnd < plan.next_full:
+            full = np.zeros(P, bool)
+            batch = self._send_steady(plan, full, rev) or self._send_entries(plan, full, rev)
+        else:
+            last = st.sync_round[K]
+            full = (last < 0) | (rnd - last >= self.full_sync_every)
+            if full.all():
+                batch = self._send_full(plan, full, rev)
+            else:
+                batch = self._send_entries(plan, full, rev)
+            st.sync_round[K[full]] = rnd
+            last = st.sync_round[K]
+            plan.next_full = -1 if (last < 0).any() else int(last.min()) + self.full_sync_every
+            self.stats.full_syncs += int(full.sum())
+        batch.pair_seqs = st.send_seq[K] & 0xFFFFFFFF
+        st.send_seq[K] += 1
+        tiebreak = next(self._seq)
+        self._seq = itertools.count(tiebreak + P)  # one seq per packet
+        heapq.heappush(
+            self._in_flight, (now + self.latency_s, tiebreak, -1, "batch", batch)
+        )
+
+    def _wire_bytes(self, P: int, n: np.ndarray, n_hb: int, n_full: int) -> int:
+        """Bytes of ``P`` packets as ``encode_packet`` lays them out,
+        ``n`` advertised columns each, ``n_hb`` heartbeats in all,
+        ``n_full`` of them carrying the site-id table."""
+        id_b = 4 if self._V.shape[1] > 0xFFFF else 2
+        q_b = np.dtype(_QUANT_DTYPES[self.quant]).itemsize
+        out = (
+            P * (2 + _HEADER.size + _CRC.size)
+            + int(n.sum()) * (id_b + 16 + (len(QUANT_FIELDS) + 1) * q_b)
+            + int(((n + 7) // 8).sum()) + n_hb * (id_b + 16)
+        )
+        if n_full:
+            if self._table_b is None:
+                self._table_b = 0
+                for name in self.peers[0].view.names:
+                    b = name.encode("utf-8")
+                    if len(b) > 255:
+                        self._table_b = None
+                        raise ValueError(f"site name too long for wire: {name!r}")
+                    self._table_b += 1 + len(b)
+            out += n_full * self._table_b
+        return out
+
+    def _wire_rows(self) -> tuple:
+        """Every peer's advertised values as the wire delivers them:
+        (queue, work, load, free slots) dequantized, and liveness."""
+        dt = _QUANT_DTYPES[self.quant]
+        return tuple(
+            x.astype(dt).astype(np.float64) for x in (self._Q, self._W, self._L, self._F)
+        ) + (self._A.copy(),)
+
+    def _send_full(self, plan: _RoundPlan, full, rev: int) -> _RoundBatch:
+        """A round where every pair full-syncs: each packet carries
+        every column its sender has not speculated on (``send``, one
+        row per sender), over the (sender, receiver, column) cube."""
+        N, S = self._V.shape
+        send = ~self._D
+        shipped = self._store.hb_stamp.reshape(N, N, S)
+        np.copyto(shipped, self._T[:, None, :], where=plan.pm[:, :, None] & send[:, None, :])
+        n = send.sum(1)[plan.I]
+        self.stats.adverts_sent += int(n.sum())
+        self.stats.bytes_sent += self._wire_bytes(len(plan.K), n, 0, len(plan.K))
+        self._quiet = None
+        return _RoundBatch(
+            plan, full, _NONE, rev,
+            dense=(send, self._V.copy(), self._T.copy(), self._wire_rows()),
+        )
+
+    def _send_steady(self, plan: _RoundPlan, full, rev: int) -> Optional[_RoundBatch]:
+        """A heartbeat round replayed from the plan, or None when it
+        would not be one: the epochs are those of the last round that
+        advertised nothing (so, acks only growing, nothing is to
+        advertise), every entry is a column its sender owns (never
+        speculated on), and every entry's stamp moved since it last
+        shipped — then every entry is a heartbeat."""
+        q = self._quiet
+        if (
+            q is None or q[0] is not plan or not plan.home_only
+            or not np.array_equal(self._V, q[1])
+        ):
+            return None
+        shipped = self._store.hb_stamp.ravel()
+        t = self._T.ravel()[plan.ic]
+        if not (t > shipped[plan.kc]).all():
+            return None
+        shipped[plan.kc] = t
+        self.stats.heartbeats_sent += len(t)
+        self.stats.bytes_sent += q[3]
+        return _RoundBatch(
+            plan, full, _NONE, rev, unique=plan.unique, steady=True,
+            h_r=plan.r, h_c=plan.c, h_v=q[2], h_t=t,
+        )
+
+    def _send_entries(self, plan: _RoundPlan, full, rev: int) -> _RoundBatch:
+        """Any other round, entry by entry: a pair that full-syncs
+        carries every column (suppression ignored), any other the
+        plan's entries whose epoch its receiver has not acknowledged
+        (advertised) or whose stamp moved since it last shipped
+        (heartbeats); neither travels where the sender speculated."""
+        st, S = self._store, self._V.shape[1]
+        r, c, unique = plan.r, plan.c, plan.unique
+        if full.any():
+            fr = np.flatnonzero(full)
+            keep = ~full[r]
+            r = np.concatenate([r[keep], np.repeat(fr, S)])
+            c = np.concatenate([c[keep], np.tile(np.arange(S), len(fr))])
+            order = np.argsort(r, kind="stable")
+            r, c, unique = r[order], c[order], False
+        ic = plan.I[r] * S + c
+        kc = plan.K[r] * S + c
+        v = self._V.ravel()[ic]
+        t = self._T.ravel()[ic]
+        acked, shipped = st.acked.ravel(), st.hb_stamp.ravel()
+        send = ~self._D.ravel()[ic]
+        fe = full[r]
+        delta = send & (fe | (v > acked[kc]))
+        hb = send & ~delta & ~fe & (t > shipped[kc])
+        told = delta | hb
+        shipped[kc[told]] = t[told]
+        d, h = np.flatnonzero(delta), np.flatnonzero(hb)
+        content = None
+        if len(d):
+            content = tuple(x.ravel()[ic[d]] for x in self._wire_rows())
+        self.stats.adverts_sent += len(d)
+        self.stats.heartbeats_sent += len(h)
+        self.stats.bytes_sent += self._wire_bytes(
+            len(plan.K), np.bincount(r[d], minlength=len(plan.K)), len(h),
+            int(full.sum()),
+        )
+        if full.any() or len(d):
+            self._quiet = None
+        else:
+            self._quiet = (
+                plan, self._V.copy(), self._V.ravel()[plan.ic],
+                self._wire_bytes(len(plan.K), _NONE, len(plan.ic), 0),
+            )
+        return _RoundBatch(
+            plan, full, _NONE, rev, unique=unique,
+            d_r=r[d], d_c=c[d], d_v=v[d], d_t=t[d], d_content=content,
+            h_r=r[h], h_c=c[h], h_v=v[h], h_t=t[h],
+        )
+
+    def _deliver_batch(self, now: float, b: _RoundBatch) -> int:
+        """``_deliver_packet`` for every packet of one batched round, in
+        send order. Returns the columns applied.
+
+        Until a peer leaves or joins, every packet reaches the pair
+        state it left, in order; after, each is checked as
+        ``_deliver_due`` and ``_deliver_packet`` check a packet."""
+        plan, st = b.plan, self._store
+        P = len(plan.pairs)
+        every = None  # fresh and acknowledged: every packet, else masks
+        fresh = acked = None
+        if len(self._churn) == b.rev:
+            if b.full.any():
+                names = list(self.peers[0].view.names)
+                for k in np.flatnonzero(b.full):
+                    st.table[plan.K[k]] = names
+            # In order (the common case): the window shifts by one.
+            K, s = plan.K, b.pair_seqs
+            top = st.recv_max[K]
+            easy = (top < 0) | (s == top + 1)
+            rows = K if (every := bool(easy.all())) else K[easy]
+            st.recv_window[rows] = np.where(
+                top[easy] < 0, np.uint64(0), (st.recv_window[rows] << np.uint64(1)) | np.uint64(1)
+            )
+            st.recv_max[rows] = s[easy]
+            if not every:
+                fresh, acked = easy.copy(), easy.copy()
+                for k in np.flatnonzero(~easy):
+                    self._accept(b, k, self._pairs[plan.pairs[k]], fresh, acked)
+        else:
+            moved = set(self._churn[b.rev:])
+            fresh, acked = np.zeros(P, bool), np.zeros(P, bool)
+            for k, (i, j) in enumerate(plan.pairs):
+                if not (self._active[j] and self._active[i]):
+                    continue
+                pair = self._pair(i, j)
+                if b.full[k]:
+                    pair.table = list(self.peers[0].view.names)
+                if pair.table is None:
+                    continue
+                self._accept(b, k, pair, fresh, acked)
+        n_fresh = P if every else int(fresh.sum())
+        n_ack = P if every else int(acked.sum())
+        applied = 0
+        if b.steady and every:
+            self._refresh_steady(b)
+        elif n_fresh:
+            self._steady = None
+            got = self._apply_full(b) if b.dense is not None and every else None
+            if got is None:
+                got = self._apply_batch(b, np.ones(P, bool) if every else fresh)
+            applied = got
+        self.stats.deliveries += n_fresh
+        self.stats.adverts_applied += applied
+        self.stats.acks_sent += n_ack
+        self.stats.bytes_sent += ACK_WIRE_BYTES * n_ack
+        if n_ack:
+            tiebreak = next(self._seq)
+            self._seq = itertools.count(tiebreak + n_ack)  # one seq per ack
+            if every:
+                acked = None
+            due = now + self.latency_s
+            if due <= now:
+                self._ack_batch(b, acked)
+            else:
+                heapq.heappush(
+                    self._in_flight, (due, tiebreak, -1, "batch_ack", (b, acked))
+                )
+        return applied
+
+    def _accept(self, b, k, pair, fresh, acked) -> None:
+        """One packet's replay-window check (``accept_seq``): it is
+        acknowledged either way, merged only when fresh."""
+        ok, reordered = pair.accept_seq(int(b.pair_seqs[k]))
+        if reordered:
+            self.stats.reordered += 1
+        if not ok:
+            self.stats.dup_suppressed += 1
+        acked[k] = True
+        fresh[k] = ok
+
+    def _refresh_steady(self, b: _RoundBatch) -> None:
+        """A steady heartbeat round's ``refresh_stamps``. When the
+        receivers' epochs and speculation are those of the last steady
+        delivery, and that one refreshed every entry not ruled out by
+        them, the same entries refresh now (their stamps were that
+        round's, older than this one's)."""
+        c = self._steady
+        if (
+            c is not None and c[0] is b.plan
+            and np.array_equal(self._V, c[1]) and np.array_equal(self._D, c[2])
+        ):
+            self._T.ravel()[c[3]] = b.h_t[c[4]]
+            return
+        at = b.plan.at
+        T = self._T.ravel()
+        fit = ~self._HC.ravel()[at] & ~self._D.ravel()[at] & (self._V.ravel()[at] == b.h_v)
+        ok = fit & (b.h_t > T[at])
+        T[at[ok]] = b.h_t[ok]
+        self._steady = (
+            (b.plan, self._V.copy(), self._D.copy(), at[ok], np.flatnonzero(ok))
+            if (ok == fit).all() else None
+        )
+
+    def _apply_full(self, b: _RoundBatch) -> Optional[int]:
+        """Every packet of an all-pairs full sync, at once, when none
+        carries an epoch newer than its receiver's or one the receiver
+        speculated on: then nothing applies and each receiver keeps the
+        freshest stamp sent for an epoch it holds (``merge_packed_rows``
+        touches). None otherwise."""
+        send, V, T, _ = b.dense
+        mine = b.plan.pm[:, :, None] & send[:, None, :] & ~self._HC[None, :, :]
+        v, held = V[:, None, :], self._V[None, :, :]
+        if (mine & (v > held)).any():
+            return None
+        equal = mine & (v == held)
+        if (equal & self._D[None, :, :]).any():
+            return None
+        np.maximum(self._T, np.where(equal, T[:, None, :], -np.inf).max(axis=0), out=self._T)
+        return 0
+
+    def _entries(self, b: _RoundBatch) -> None:
+        """Fill a dense batch's advertised entries."""
+        send, V, T, rows = b.dense
+        r, c = np.nonzero(send[b.plan.I])
+        i = b.plan.I[r]
+        b.d_r, b.d_c, b.d_v, b.d_t = r, c, V[i, c], T[i, c]
+        b.d_content = tuple(x[i, c] for x in rows)
+
+    def _apply_batch(self, b: _RoundBatch, fresh: np.ndarray) -> int:
+        """``receive_packed`` then ``refresh_stamps`` for the fresh
+        packets of a round, over the exchange's (N, S) arrays: at once
+        when no (receiver, column) is carried twice, else sender by
+        sender in send order (one sender's packets go to distinct
+        receivers). Returns the columns applied."""
+        if b.dense is not None and not len(b.d_r):
+            self._entries(b)
+        S = self._V.shape[1]
+        J = b.plan.J
+        dm, hm = fresh[b.d_r], fresh[b.h_r]
+        d_r, h_r = b.d_r[dm], b.h_r[hm]
+        d_at, h_at = J[d_r] * S + b.d_c[dm], J[h_r] * S + b.h_c[hm]
+        dv, dt, hv, ht = b.d_v[dm], b.d_t[dm], b.h_v[hm], b.h_t[hm]
+        if b.unique:
+            put = self._merge_entries(d_at, dv, dt)
+            self._refresh_entries(h_at, hv, ht)
+            puts = [(d_at[put], np.flatnonzero(dm)[put])]
+        else:
+            I = b.plan.I
+            d_i, h_i = I[d_r], I[h_r]
+            puts = []
+            for i in np.unique(np.concatenate([d_i, h_i])):
+                ds, hs = d_i == i, h_i == i
+                put = self._merge_entries(d_at[ds], dv[ds], dt[ds])
+                puts.append((d_at[ds][put], np.flatnonzero(dm)[ds][put]))
+                self._refresh_entries(h_at[hs], hv[hs], ht[hs])
+        at = np.concatenate([p[0] for p in puts])
+        if not len(at):
+            return 0
+        src = np.concatenate([p[1] for p in puts])
+        applied = len(src)
+        if not b.unique:
+            # A (receiver, column) merged twice keeps the later content.
+            last = len(at) - 1 - np.unique(at[::-1], return_index=True)[1]
+            at, src = at[last], src[last]
+        for x, vals in zip((self._Q, self._W, self._L, self._F, self._A), b.d_content):
+            x.ravel()[at] = vals[src]
+        return applied
+
+    def _merge_entries(self, at, v, t) -> np.ndarray:
+        """``merge_packed_rows`` on flat (receiver, column) entries
+        ``at``, none twice: a strictly newer epoch applies, or an equal
+        one over the receiver's own speculation; an equal epoch with a
+        fresher stamp refreshes the stamp; home columns are protected.
+        Returns which entries applied."""
+        V, T, D = self._V.ravel(), self._T.ravel(), self._D.ravel()
+        mine = ~self._HC.ravel()[at]
+        held = V[at]
+        equal = mine & (v == held)
+        put = (mine & (v > held)) | (equal & D[at])
+        touch = equal & ~put & (t > T[at])
+        T[at[put]] = np.maximum(T[at[put]], t[put])
+        T[at[touch]] = t[touch]
+        V[at[put]] = v[put]
+        D[at[put]] = False
+        return put
+
+    def _refresh_entries(self, at, v, t) -> None:
+        """``refresh_stamps`` on flat (receiver, column) entries, none
+        twice: the echoed epoch held, not home, not speculated, and a
+        fresher stamp."""
+        T = self._T.ravel()
+        ok = (
+            ~self._HC.ravel()[at] & ~self._D.ravel()[at]
+            & (self._V.ravel()[at] == v) & (t > T[at])
+        )
+        T[at[ok]] = t[ok]
+
+    def _ack_batch(self, b: _RoundBatch, acked: np.ndarray) -> None:
+        """``_apply_ack`` for the acknowledgements of one batched round
+        (``acked`` None: every packet): a pair touched by a leave or
+        join since the send has no pending packet left to acknowledge."""
+        if b.dense is None and not len(b.d_r):
+            return  # no column was advertised: nothing to advance
+        if acked is None:
+            acked = np.ones(len(b.plan.pairs), bool)
+        if len(self._churn) > b.rev:
+            moved = set(self._churn[b.rev:])
+            acked = acked & np.asarray(
+                [not (i in moved or j in moved) for i, j in b.plan.pairs]
+            )
+        if b.dense is not None:
+            send, V = b.dense[0], b.dense[1]
+            N, S = V.shape
+            ok = np.zeros((N, N), bool)
+            ok[b.plan.I[acked], b.plan.J[acked]] = True
+            A = self._store.acked.reshape(N, N, S)
+            np.copyto(A, np.maximum(A, V[:, None, :]), where=ok[:, :, None] & send[:, None, :])
+            return
+        m = acked[b.d_r]
+        at = b.plan.K[b.d_r[m]] * self._V.shape[1] + b.d_c[m]
+        A = self._store.acked.ravel()
+        A[at] = np.maximum(A[at], b.d_v[m])
+
     def _deliver_packet(
         self, now: float, sender: int, j: int, buf: bytes, seq: int
     ) -> int:
@@ -1871,6 +2549,7 @@ class GossipExchange:
             return 0
         names = pair.table
         recv = self.peers[j]
+        self._steady = None
         applied = recv.receive_packed(
             names=[names[c] for c in pkt["ids"]],
             qrows=pkt["rows"],

@@ -84,7 +84,15 @@ class SimConfig:
     topology: Optional[GridTopology] = None
 
     # -- P2PGridSim only --------------------------------------------------
-    num_peers: int = 3
+    #: The deployment's ownership: one list of site names per peer, the
+    #: list's first site the peer's home (its advertised link row),
+    #: every site in exactly one list. None deals the sites round-robin
+    #: in sorted-name order over ``num_peers`` peers, each homed at its
+    #: first site.
+    peer_sites: Optional[list[list[str]]] = None
+    #: Peers of the round-robin deal; None is 3, or, with ``peer_sites``,
+    #: the partition's count, which a given value must equal.
+    num_peers: Optional[int] = None
     exchange_interval_s: float = 60.0
     exchange_latency_s: float = 0.0
     migration_max_staleness_s: Optional[float] = None
@@ -111,7 +119,7 @@ class SimConfig:
 
 
 _P2P_FIELDS = frozenset({
-    "num_peers", "exchange_interval_s", "exchange_latency_s",
+    "peer_sites", "num_peers", "exchange_interval_s", "exchange_latency_s",
     "migration_max_staleness_s", "gossip_fanout",
     "gossip_wire", "gossip_quant", "gossip_full_sync_every",
     "transport_faults", "gossip_summaries",

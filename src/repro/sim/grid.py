@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import heapq
 import itertools
-from collections import deque
+from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -246,6 +246,7 @@ class GridSim:
             name: _Site(name, n, self.quotas, use_mlfq=(policy == "diana"))
             for name, n in site_nodes.items()
         }
+        self._queued_hint: Optional[_Site] = None  # see _queued_anywhere
         self.central_fifo: deque[Job] = deque()  # fcfs policy only
         self._cj2sj: dict[int, SimJob] = {}
         self._seq = itertools.count()
@@ -948,18 +949,26 @@ class GridSim:
         """Whether the periodic events (migrate/exchange) should keep
         rescheduling: queued jobs anywhere, or arrivals still to come.
         One predicate for both so they always stop together."""
-        return any(s.queue_len() for s in self.sites.values()) or any(
-            e[2] == "arrive" for e in events
-        )
+        return self._queued_anywhere() or any(e[2] == "arrive" for e in events)
 
     def _stream_work_remaining(self, cursor: _ArrivalCursor) -> bool:
         """``_work_remaining`` for the horizon loop: pending arrivals
         live in the cursor, not the heap. Equivalent predicate — in
         both loops an arrival pending at decision time is strictly in
         the future."""
-        return any(s.queue_len() for s in self.sites.values()) or (
-            cursor.peek_time() != float("inf")
-        )
+        return self._queued_anywhere() or cursor.peek_time() != float("inf")
+
+    def _queued_anywhere(self) -> bool:
+        """Whether any site has a queued job, asking first the site that
+        had one last time (a long queue answers for many periods)."""
+        site = self._queued_hint
+        if site is not None and site.queue_len():
+            return True
+        for site in self.sites.values():
+            if site.queue_len():
+                self._queued_hint = site
+                return True
+        return False
 
     # -- multi-scheduler hooks (no-ops in the omniscient base sim) -----------
     #: §IX trust horizon: peers whose advertised rows are older than this
@@ -1261,11 +1270,10 @@ class GridSim:
         genuinely depends on earlier sites' moves — a global upfront
         collection could not stay bit-identical)."""
         with trace.span("diana.sim.migrate"):
-            batched = (
-                self.batch_migration
-                and self.policy == "diana"
-                and self._link_matrices_ready()
-            )
+            batched = self.batch_migration and self.policy == "diana"
+            if batched and not any(s.mlfq.jobs for s in self.sites.values()):
+                return  # no queued job: no candidate anywhere
+            batched = batched and self._link_matrices_ready()
             if not batched:
                 for name, site in self.sites.items():
                     if (
@@ -1279,7 +1287,10 @@ class GridSim:
             sp: Optional[SitePack] = None
             idx = self._site_idx
             for name, site in self.sites.items():
-                if not site.use_mlfq or not site.alive:
+                # An empty queue has no Q4 candidate; skipping its rate
+                # check changes nothing (the samples it would prune are
+                # older than any window a later check counts).
+                if not site.mlfq.jobs or not site.use_mlfq or not site.alive:
                     continue
                 if not site.mlfq.congested(self.congestion_window_s, now):
                     continue
@@ -1610,12 +1621,35 @@ class GridSim:
             )
 
 
+def _stated_partition(peer_sites, names: list[str]) -> list[list[str]]:
+    """The deployment's ownership, checked: one non-empty list of site
+    names per peer, every site of the grid in exactly one list."""
+    partition = [list(p) for p in peer_sites]
+    empty = [k for k, p in enumerate(partition) if not p]
+    if empty:
+        raise ValueError(f"peer_sites: peer(s) {empty} own no site")
+    seen = Counter(n for p in partition for n in p)
+    twice = sorted(n for n, c in seen.items() if c > 1)
+    unknown = sorted(set(seen) - set(names))
+    missing = sorted(set(names) - set(seen))
+    if twice or unknown or missing:
+        raise ValueError(
+            "peer_sites must hold every site exactly once: "
+            f"twice {twice}, not in the grid {unknown}, missing {missing}"
+        )
+    return partition
+
+
 class P2PGridSim(GridSim):
     """Multi-scheduler mode: the paper's decentralized deployment
     (§III/§IX) over the same event stream.
 
-    The grid's sites are partitioned round-robin (sorted order) across
-    ``num_peers`` ``PeerScheduler``s. Each peer owns its partition's
+    Each ``PeerScheduler`` owns the sites the deployment states for it
+    (``SimConfig.peer_sites``: one list per peer, its first site the
+    peer's home, every site exactly once; e.g. one peer per RootGrid
+    region, homed at the region's Tier-0 or Tier-1). Without a stated
+    partition the sites are dealt round-robin in sorted-name order
+    over ``num_peers`` peers. Each peer owns its partition's
     authoritative state and sees every other site only through the
     gossip exchange: every ``exchange_interval_s`` each peer
     re-measures its home rows and advertises its whole world view to
@@ -1664,7 +1698,18 @@ class P2PGridSim(GridSim):
         topology = cfg.topology
         gossip_fanout = cfg.gossip_fanout
         names = self._names_sorted
-        N = max(1, min(int(cfg.num_peers), len(names)))
+        if cfg.peer_sites is None:
+            N = 3 if cfg.num_peers is None else int(cfg.num_peers)
+            N = max(1, min(N, len(names)))
+            partition = [names[i::N] for i in range(N)]
+        else:
+            partition = _stated_partition(cfg.peer_sites, names)
+            N = len(partition)
+            if cfg.num_peers is not None and cfg.num_peers != N:
+                raise ValueError(
+                    f"num_peers={cfg.num_peers} disagrees with peer_sites, "
+                    f"which states {N} peers"
+                )
         self.num_peers = N
         if migration_max_staleness_s is None:
             # Default trust horizon in rounds-behind: a freshly-heard
@@ -1693,8 +1738,8 @@ class P2PGridSim(GridSim):
         # back to a placeholder (the public cost planes are then
         # meaningless, like the sequential fallback paths).
         self.peers = []
-        for i in range(N):
-            home = names[i]
+        for home_sites in partition:
+            home = home_sites[0]
             try:
                 plinks = {n: self.links[(home, n)] for n in names}
             except KeyError:
@@ -1702,7 +1747,7 @@ class P2PGridSim(GridSim):
             self.peers.append(
                 PeerScheduler(
                     home=home, sites=states, links=plinks,
-                    weights=self.weights, home_sites=names[i::N], order=names,
+                    weights=self.weights, home_sites=home_sites, order=names,
                 )
             )
         self._peer_by_site = {}
@@ -1775,25 +1820,26 @@ class P2PGridSim(GridSim):
         peer's world view: home columns are re-measured per job (the
         peer owns them — same freshness as the omniscient sim), remote
         columns are whatever the last exchange advertised."""
-        peer = self._submit_peer(sj)
-        peer.refresh_home()
-        out = comp_site_column(peer.view, self.weights) + sj.work / peer.view.cap
-        alive = peer.view.alive
-        if not alive.all():
-            # Mask sites this peer BELIEVES are dead (home columns are
-            # authoritative; remote columns only as fresh as the last
-            # advert — a stale view may still aim at a dead site and
-            # bounce in _admit, which is the point).
-            out = np.where(alive, out, np.inf)
-        mask = self._suspect_mask_for(peer)
-        if mask is not None:
-            # Prefer owner-direct knowledge: columns owned by a
-            # suspect peer carry state of unknown age, so avoid them —
-            # unless that would leave nowhere finite to place.
-            masked = np.where(mask, np.inf, out)
-            if np.isfinite(masked).any():
-                out = masked
-        return out
+        with trace.span("diana.p2p.view"):
+            peer = self._submit_peer(sj)
+            peer.refresh_home()
+            out = comp_site_column(peer.view, self.weights) + sj.work / peer.view.cap
+            alive = peer.view.alive
+            if not alive.all():
+                # Mask sites this peer BELIEVES are dead (home columns are
+                # authoritative; remote columns only as fresh as the last
+                # advert — a stale view may still aim at a dead site and
+                # bounce in _admit, which is the point).
+                out = np.where(alive, out, np.inf)
+            mask = self._suspect_mask_for(peer)
+            if mask is not None:
+                # Prefer owner-direct knowledge: columns owned by a
+                # suspect peer carry state of unknown age, so avoid them —
+                # unless that would leave nowhere finite to place.
+                masked = np.where(mask, np.inf, out)
+                if np.isfinite(masked).any():
+                    out = masked
+            return out
 
     def choose_site(self, sj: SimJob) -> str:
         comp = self._comp_vec(sj)
@@ -1834,7 +1880,10 @@ class P2PGridSim(GridSim):
         # placement sees this one. Home targets get truth on the next
         # refresh; remote targets keep the (dirty, never re-advertised)
         # estimate until the owner's advert corrects it.
-        self._submit_peer(sj).note_remote_placement(target, sj.work)
+        peer = self._submit_peer(sj)
+        if trace.on and target not in peer.home_sites:
+            trace.count("diana.p2p.remote_placements")
+        peer.note_remote_placement(target, sj.work)
         return target
 
     # -- peer churn (fault plan peer_leave/peer_join) --------------------------

@@ -1,5 +1,6 @@
 """Spans and counters (``repro.core.trace``): off by default and free of
 effect on decisions; on, the counters read what the calls did."""
+import contextlib
 import copy
 
 import numpy as np
@@ -124,3 +125,65 @@ def test_reprioritized_counts_queue_depth_at_each_submit(tracing_on):
     c = trace.counters()
     assert c["diana.mlfq.submits"] == 5
     assert c["diana.mlfq.reprioritized"] == sum(depths)
+
+
+class _SpanLog:
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts each span
+    name entered."""
+
+    def __init__(self):
+        self.names: list[str] = []
+
+    def __call__(self, name):
+        self.names.append(name)
+        return contextlib.nullcontext()
+
+
+def _p2p_run(**kw):
+    """A three-peer P2PGridSim run with 2 s gossip latency; migration
+    ticks only after the last job, so each job's site is where the
+    submitting peer placed it."""
+    from repro.sim import P2PGridSim, SimConfig, SimJob, paper_grid_spec
+
+    rng = np.random.default_rng(4)
+    names = sorted(paper_grid_spec())
+    jobs = [SimJob(user=f"u{k % 3}", arrival=1.5 * k, work=float(rng.uniform(20, 90)),
+                   input_bytes=float(rng.uniform(0, 2e9)), output_bytes=1e7,
+                   data_site=names[int(rng.integers(5))],
+                   origin_site=names[int(rng.integers(5))])
+            for k in range(120)]
+    sim = P2PGridSim(paper_grid_spec(), config=SimConfig(
+        policy="diana", num_peers=3, exchange_interval_s=30.0, exchange_latency_s=2.0,
+        migration_interval_s=1e6, **kw))
+    return sim, sim.run(jobs)
+
+
+def test_p2p_off_by_default_counts_nothing():
+    assert not trace.on
+    _p2p_run()
+    assert trace.counters() == {}
+
+
+def test_p2p_counters_equal_exchange_stats_and_placements(tracing_on, monkeypatch):
+    spans = _SpanLog()
+    monkeypatch.setattr(trace, "_annotation", spans)
+    sim, res = _p2p_run()
+    c, stats = trace.counters(), sim.exchange.stats
+    assert c["diana.p2p.rounds"] == stats.rounds > 0
+    assert c["diana.p2p.packets"] == stats.deliveries > 0
+    assert c["diana.p2p.bytes"] == stats.bytes_sent > 0
+    assert c["diana.p2p.rows_merged"] == stats.adverts_applied > 0
+    remote = sum(1 for j in res.jobs
+                 if j.exec_site not in sim._peer_by_site[j.origin_site].home_sites)
+    assert c["diana.p2p.remote_placements"] == remote > 0
+    assert spans.names.count("diana.p2p.round") == c["diana.p2p.rounds"]
+    assert spans.names.count("diana.p2p.view") == len(res.jobs)
+    # every exchange event and every delivery event drains the heap once
+    assert spans.names.count("diana.p2p.deliver") >= c["diana.p2p.rounds"]
+
+
+def test_p2p_decisions_bit_identical_with_tracing_on(tracing_on):
+    on = _p2p_run()[1]
+    trace.disable()
+    off = _p2p_run()[1]
+    assert [(j.exec_site, j.finish) for j in on.jobs] == [(j.exec_site, j.finish) for j in off.jobs]
