@@ -32,6 +32,7 @@ __all__ = [
     "priority",
     "queue_index",
     "reprioritize",
+    "reprioritize_row",
     "NUM_QUEUES",
     "QUEUE_BOUNDS",
 ]
@@ -114,6 +115,27 @@ def reprioritize_np(
     pr = np.where(n <= N, (N - n) / N, (N - n) / n)
     qidx = (pr < 0.5).astype(np.int32) + (pr < 0.0) + (pr < -0.5)
     return pr, qidx.astype(np.int32)
+
+
+def reprioritize_row(
+    n: float, q: float, t: float, quota_sum: float, proc_sum: float
+) -> tuple[float, int]:
+    """One row of ``reprioritize_np``: the same float64 operations in
+    the same order, so the same bits, and the same band (nan in Q1).
+
+    ``n``, ``q`` and ``t`` come rounded through float32, as the rows of
+    ``reprioritize_np`` do. Where Python refuses a division by zero the
+    row goes through ``reprioritize_np`` itself, with NumPy's inf.
+    """
+    try:
+        N = (q * proc_sum) / (quota_sum * t)
+    except ZeroDivisionError:
+        pr, qidx = reprioritize_np(
+            np.float32([n]), np.float32([q]), np.float32([t]), quota_sum, proc_sum
+        )
+        return float(pr[0]), int(qidx[0])
+    p = (N - n) / N if n <= N else (N - n) / n
+    return p, (p < 0.5) + (p < 0.0) + (p < -0.5)
 
 
 def littles_law_queue_length(arrival_rate: float, wait_time: float) -> float:

@@ -115,7 +115,7 @@ class ServingEngine:
             del self.pending[job.job_id]
             batch.append(req)
         for job in skipped:              # requeue preserved (FCFS keeps order)
-            self.queues.jobs.append(job)
+            self.queues.requeue(job)
         return batch
 
     def _decode_batch(self, batch: list[InferenceRequest]):

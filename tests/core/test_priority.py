@@ -131,3 +131,24 @@ class TestPriorityProperties:
             prio.threshold(q=0, Q=1, t=1, T=1)
         with pytest.raises(ValueError):
             prio.priority(n=0, N=1.0)
+
+
+@pytest.mark.parametrize("n, q, t, Q, T", [
+    (3.0, 1900.0, 5.0, 3600.0, 17.0),        # over the threshold
+    (1.0, 1700.0, 1.0, 3600.0, 40.0),        # under it
+    (2.0, 0.1, 0.5, 2.9, 7.25),              # fractional quota and t
+    (1.0, 0.0, 1.0, 1.0, 3.0),               # a zero quota: N = 0
+    (1.0, 1.0, 1.0, 0.0, 3.0),               # Q = 0: NumPy's inf
+    (1.0, 0.0, 1.0, 0.0, 3.0),               # 0 / 0: NumPy's nan, in Q1
+    (1.0, 3.0e38, 1.0, 1.0, 1.0e300),        # q·T overflows: inf / inf
+])
+def test_reprioritize_row_is_a_row_of_reprioritize_np(n, q, t, Q, T):
+    """The scalar row the class-keyed queues use gives the vectorized
+    path's bits and band, its inf and nan included."""
+    with np.errstate(divide="ignore", invalid="ignore", over="ignore"):
+        pr, qidx = prio.reprioritize_np(
+            np.float32([n]), np.float32([q]), np.float32([t]), Q, T
+        )
+        p, qi = prio.reprioritize_row(n, float(np.float32(q)), float(np.float32(t)), Q, T)
+    assert np.float64(p).tobytes() == pr[0].tobytes()
+    assert qi == qidx[0]

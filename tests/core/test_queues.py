@@ -1,4 +1,7 @@
 """§VI/§VII/§X multilevel feedback queues."""
+from collections import Counter
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 try:
@@ -154,3 +157,157 @@ def test_littles_law_steady_state_simulation():
     measured_W = float(np.mean(waits))
     # Little: N = R·W — generous tolerance for finite-run noise.
     assert measured_N == pytest.approx(arrival_rate * measured_W, rel=0.25, abs=0.2)
+
+
+class _ReferenceQueues:
+    """The O(L) §X queues the class-keyed ones replace: every submit
+    re-prioritizes every queued job over the list, and a dispatch is a
+    linear ``min`` over it."""
+
+    def __init__(self, quotas):
+        self.quotas = dict(quotas)
+        self.jobs = []
+
+    def submit(self, job):
+        self.quotas.setdefault(job.user, 1.0)
+        self.jobs.append(job)
+        users = {j.user for j in self.jobs}
+        Q = sum(self.quotas.get(u, 1.0) for u in users)
+        T = sum(j.t for j in self.jobs)
+        counts = Counter(j.user for j in self.jobs)
+        n = np.array([counts[j.user] for j in self.jobs], np.float32)
+        q = np.array([self.quotas[j.user] for j in self.jobs], np.float32)
+        t = np.array([j.t for j in self.jobs], np.float32)
+        pr, qidx = prio.reprioritize_np(n, q, t, Q, T)
+        for j, p, qi in zip(self.jobs, pr, qidx):
+            j.priority, j.queue = float(p), int(qi)
+
+    def submit_batch(self, jobs):
+        for j in sorted(jobs, key=lambda j: (j.t, j.submit_time, j.job_id)):
+            self.submit(j)
+
+    def pop_next(self):
+        if not self.jobs:
+            return None
+        best = min(self.jobs, key=lambda j: (-j.priority, j.submit_time, j.job_id))
+        self.remove(best)
+        return best
+
+    def remove(self, job):
+        del self.jobs[next(i for i, j in enumerate(self.jobs) if j is job)]
+
+    def requeue(self, job):
+        self.jobs.append(job)
+
+
+# (quotas, t values) per regime: integral values take the running
+# totals; a fractional quota or t takes the in-order sums while a job
+# of it is queued, so the fractional_* regimes cross between the two.
+_REGIMES = {
+    "integral": ({"u0": 1900.0, "u1": 1700.0, "u2": 1.0, "u3": 3}, [1, 1.0, 5, 2]),
+    "fractional": ({"u0": 0.1, "u1": 0.7, "u2": 1 / 3, "u3": 2.5}, [0.5, 1 / 3, 2.25, 1.1]),
+    "fractional_quota": ({"u0": 0.1, "u1": 0.7, "u2": 1 / 3, "u3": 2.5}, [1, 2, 5]),
+    "fractional_t": ({"u0": 2.0, "u1": 7.0, "u2": 4.0}, [1, 2, 0.5, 4]),
+    "one_user_several_t": ({"u0": 7.0}, [1, 2, 3, 5, 8]),
+}
+
+
+@pytest.mark.parametrize("regime", sorted(_REGIMES))
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_class_queues_match_the_reference_bit_for_bit(regime, seed):
+    """Random interleavings of submit, submit_batch, pop_next, remove,
+    requeue, priority writes (§IX's ``apply_migration`` on a queued job)
+    and quota changes: the same pops, the same jobs order, and every
+    queued job's priority and band equal to the bit after every
+    operation."""
+    quotas, ts = _REGIMES[regime]
+    users = sorted(quotas) + ["new"]        # "new" has no quota: 1.0
+    rng = np.random.default_rng(seed)
+    q, ref = MultilevelFeedbackQueues(quotas), _ReferenceQueues(quotas)
+    pairs, popped, k = {}, [], 0
+
+    def new_pair():
+        nonlocal k
+        k += 1
+        fields = dict(user=users[rng.integers(len(users))], t=ts[rng.integers(len(ts))],
+                      submit_time=float(rng.integers(0, 8)), job_id=k)
+        job, rjob = Job(**fields), SimpleNamespace(priority=0.0, queue=1, **fields)
+        pairs[k] = (job, rjob)
+        return job, rjob
+
+    for _ in range(400):
+        op = rng.choice(["submit", "batch", "pop", "pop", "remove", "requeue", "write", "quota"])
+        if op == "submit":
+            job, rjob = new_pair()
+            q.submit(job)
+            ref.submit(rjob)
+        elif op == "batch":
+            made = [new_pair() for _ in range(rng.integers(2, 5))]
+            q.submit_batch([m[0] for m in made])
+            ref.submit_batch([m[1] for m in made])
+        elif op == "pop":
+            job, rjob = q.pop_next(), ref.pop_next()
+            assert (job and job.job_id) == (rjob and rjob.job_id)
+            if job is not None:
+                assert (job.priority, job.queue) == (rjob.priority, rjob.queue)
+                popped.append(job.job_id)
+        elif op == "remove" and len(q):
+            jid = list(q.jobs)[rng.integers(len(q))].job_id
+            q.remove(pairs[jid][0])
+            ref.remove(pairs[jid][1])
+        elif op == "requeue" and popped:
+            jid = popped.pop(rng.integers(len(popped)))
+            q.requeue(pairs[jid][0])
+            ref.requeue(pairs[jid][1])
+        elif op == "write" and len(q):
+            jid = list(q.jobs)[rng.integers(len(q))].job_id
+            for j in pairs[jid]:
+                j.priority = min(1.0, j.priority + 0.1)
+        elif op == "quota":          # read at the next submit; a NumPy float
+            user, value = rng.choice(sorted(quotas)), rng.choice(list(quotas.values()))
+            q.quotas[user] = ref.quotas[user] = value
+        assert [j.job_id for j in q.jobs] == [j.job_id for j in ref.jobs]
+        assert [(j.priority, j.queue) for j in q.jobs] == [(j.priority, j.queue) for j in ref.jobs]
+        for p in (-0.5, 0.0, 0.5):
+            assert q.jobs_ahead(p) == sum(1 for j in ref.jobs if j.priority >= p)
+        assert [j.job_id for j in q.low_priority_jobs()] == [
+            j.job_id for j in ref.jobs if j.queue == prio.NUM_QUEUES - 1
+        ]
+
+
+def test_priority_written_on_a_queued_job_holds_until_the_next_submit():
+    """§IX's bump on a job still queued: the next pop sees the written
+    priority, only that job's, and the next submit recomputes it."""
+    q = MultilevelFeedbackQueues({"a": 1.0, "b": 1.0})
+    a1, a2 = q.submit(Job(user="a", submit_time=0.0)), q.submit(Job(user="a", submit_time=1.0))
+    b = q.submit(Job(user="b", submit_time=2.0))
+    assert b.priority > a1.priority == a2.priority
+    a2.priority = 0.99
+    assert a1.priority != 0.99
+    assert q.jobs_ahead(0.99) == 1
+    assert q.pop_next() is a2
+    assert a2.priority == 0.99
+    q.submit(Job(user="b", submit_time=3.0))
+    a1.priority = 0.99
+    q.submit(Job(user="a", submit_time=4.0))
+    assert a1.priority < 0.99                # recomputed with the rest
+
+
+def test_requeue_keeps_priority_and_goes_to_the_end():
+    """The serving engine's requeue of a request it skipped: back at the
+    end of ``jobs`` with the priority it left with, nothing else moved."""
+    q = MultilevelFeedbackQueues({"a": 1.0, "b": 4.0})
+    jobs = [q.submit(Job(user=u, submit_time=float(i))) for i, u in enumerate("aabb")]
+    head = q.pop_next()
+    before = [(j.job_id, j.priority, j.queue) for j in q.jobs]
+    kept = (head.priority, head.queue)
+    q.requeue(head)
+    assert list(q.jobs)[-1] is head and len(q) == 4
+    assert (head.priority, head.queue) == kept
+    assert [(j.job_id, j.priority, j.queue) for j in q.jobs][:3] == before
+    assert q.pop_next() is head              # still the highest
+    q.requeue(head)
+    with pytest.raises(ValueError):
+        q.requeue(head)                      # already queued
+    q.submit(Job(user="a", submit_time=9.0))
+    assert head.user == "b" and head.priority == jobs[3].priority != kept[0]

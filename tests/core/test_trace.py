@@ -127,6 +127,30 @@ def test_reprioritized_counts_queue_depth_at_each_submit(tracing_on):
     assert c["diana.mlfq.reprioritized"] == sum(depths)
 
 
+@pytest.mark.parametrize("quota_b, t_step", [
+    (2.0, 1.0),             # integers: running totals, no fallback
+    (2.5, 1.0),             # a fractional quota, while b has jobs queued
+    (2.0, 0.5),             # fractional t, while such a job is queued
+])
+def test_classes_and_exact_fallback_per_submit(tracing_on, quota_b, t_step):
+    """``diana.mlfq.classes`` counts the (user, t) rows each submit
+    recomputes; ``diana.mlfq.exact_fallback`` the submits whose Q or T
+    could not come from running totals exactly."""
+    quotas = {"a": 1.0, "b": quota_b}
+    q = MultilevelFeedbackQueues(quotas)
+    classes, fallbacks = [], 0
+    for k, user in enumerate("aabab"):
+        q.submit(Job(user=user, t=1.0 + t_step * (k % 2), submit_time=float(k)))
+        classes.append(len({(j.user, j.t) for j in q.jobs}))
+        fallbacks += any(quotas[j.user] % 1 or j.t % 1 for j in q.jobs)
+        if k == 2:
+            q.pop_next()
+    c = trace.counters()
+    assert c["diana.mlfq.classes"] == sum(classes)
+    assert c.get("diana.mlfq.exact_fallback", 0) == fallbacks
+    assert (fallbacks == 0) == (quota_b % 1 == t_step % 1 == 0)
+
+
 class _SpanLog:
     """Stands in for ``jax.profiler.TraceAnnotation``: counts each span
     name entered."""

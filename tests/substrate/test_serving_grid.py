@@ -89,6 +89,25 @@ class TestServingEngine:
         later_hogs = sum(1 for h in hogs if h.first_token_time > vip.first_token_time)
         assert later_hogs >= 3  # vip overtook most of the flood
 
+    def test_skipped_prompt_lengths_are_requeued(self, engine_setup):
+        """A request whose prompt length differs from its batch's goes
+        back to the queue with its priority, behind the rest, and is
+        served in a later batch."""
+        cfg, lm, params = engine_setup
+        rng = np.random.default_rng(7)
+        eng = ServingEngine(lm, params, num_slots=2, max_len=32)
+        reqs = [_req(cfg, "u", rng, plen=p) for p in (6, 4, 6, 4)]
+        for i, r in enumerate(reqs):
+            eng.submit(r, now=float(i))
+        skipped = {r.rid: (j.priority, j.queue) for r in reqs[1::2]
+                   for j in eng.queues.jobs if j.job_id == r.rid}
+        assert [r.rid for r in eng._form_batch(now=4.0)] == [reqs[0].rid, reqs[2].rid]
+        # reqs[3] was never popped; the skipped reqs[1] now queues behind it
+        assert [j.job_id for j in eng.queues.jobs] == [reqs[3].rid, reqs[1].rid]
+        assert {j.job_id: (j.priority, j.queue) for j in eng.queues.jobs} == skipped
+        assert eng.run_until_drained().served == 2
+        assert all(r.done for r in reqs[1::2])
+
     def test_prefix_cache_hits(self, engine_setup):
         cfg, lm, params = engine_setup
         rng = np.random.default_rng(3)
