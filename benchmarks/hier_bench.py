@@ -11,10 +11,9 @@ Sites are tier-structured (each tier draws its WAN quality around a
 tier-characteristic bandwidth/loss/RTT — the locality premise behind
 the RootGrid hierarchy). On structureless uniform-random link tables
 the tier bounds cannot prune and hier degrades to a slower dense scan;
-that regime stays on ``placement="flat"``.
+that regime stays on the flat ``place_batch``.
 
-Writes ``BENCH_hier.json`` (scale record + GridSim/P2PGridSim
-equivalence pins at 256 and 1k sites) when run as a script:
+Writes ``BENCH_hier.json`` (the scale record) when run as a script:
 
     PYTHONPATH=src python benchmarks/hier_bench.py [--jobs N] [--sites S] [--tiers T]
 """
@@ -29,7 +28,7 @@ import tracemalloc
 
 import numpy as np
 
-from repro.core import DianaScheduler, GridTopology, Job, NetworkLink, Node, SiteState
+from repro.core import DianaScheduler, Job, NetworkLink, SiteState
 
 try:
     from .common import emit
@@ -122,73 +121,6 @@ def bench_scale(jobs: int = 100_000, sites: int = 10_000,
     }
 
 
-# -- simulator equivalence pins ------------------------------------------------
-
-def _build_sim(n_sites: int, tiers_n: int, seed: int):
-    from repro.sim.workloads import SimJob
-
-    rng = np.random.default_rng(seed)
-    names = [f"s{i:04d}" for i in range(n_sites)]
-    spec = {n: int(rng.integers(1, 5)) for n in names}
-    tier_bw = rng.uniform(1e7, 1e9, tiers_n)
-    tier_loss = rng.uniform(0.0, 0.02, tiers_n)
-    links = {}
-    for a_i, a in enumerate(names):
-        ta = a_i % tiers_n
-        for b_i, b in enumerate(names):
-            tb = b_i % tiers_n
-            links[(a, b)] = NetworkLink(
-                bandwidth_Bps=float(min(tier_bw[ta], tier_bw[tb])
-                                    * rng.uniform(0.8, 1.25)),
-                loss_rate=0.0 if a == b else float(
-                    max(tier_loss[ta], tier_loss[tb]) * rng.uniform(0.8, 1.25)),
-                rtt_s=float(rng.uniform(0.01, 0.3)),
-            )
-    topo = GridTopology()
-    for i, n in enumerate(names):
-        topo.join(f"root{i % tiers_n}", Node(name=n))
-    jobs = [
-        SimJob(
-            user=("hog" if i % 5 == 0 else f"u{i % 7}"),
-            arrival=float(i // 8) * 5.0,
-            work=float(rng.integers(10, 600)),
-            input_bytes=float(rng.choice([0.0, 1e6, 5e9])),
-            output_bytes=float(rng.choice([0.0, 2e8])),
-            data_site=(names[i % n_sites] if i % 3 else None),
-            origin_site=names[(i * 7) % n_sites],
-        )
-        for i in range(800)
-    ]
-    return spec, links, topo, jobs
-
-
-def bench_sim_equivalence(n_sites: int, tiers_n: int, seed: int = 0) -> dict:
-    """hier ≡ flat on full GridSim and P2PGridSim event streams."""
-    from repro.sim import GridSim, P2PGridSim, SimConfig
-
-    spec, links, topo, jobs = _build_sim(n_sites, tiers_n, seed)
-    out = {"sites": n_sites, "tiers": tiers_n}
-    for label, cls, kw in (
-        ("gridsim", GridSim, {}),
-        ("p2p", P2PGridSim, dict(num_peers=8, exchange_interval_s=60.0)),
-    ):
-        traces = {}
-        for placement in ("flat", "hier"):
-            cfg = SimConfig(policy="diana", placement=placement, topology=topo,
-                            migration_interval_s=30.0,
-                            congestion_window_s=120.0, **kw)
-            sim = cls(dict(spec), links=dict(links), config=cfg)
-            res = sim.run(copy.deepcopy(jobs))
-            traces[placement] = [
-                (j.user, j.arrival, j.exec_site, j.finish, j.migrated)
-                for j in res.jobs
-            ]
-        identical = traces["flat"] == traces["hier"]
-        assert identical, f"{label}@{n_sites}: hier diverged from flat"
-        out[f"{label}_identical"] = identical
-    return out
-
-
 def run() -> dict:
     """Harness entry (reduced size to stay quick)."""
     rec = bench_scale(jobs=5_000, sites=2_000, tiers_n=40)
@@ -197,7 +129,6 @@ def run() -> dict:
         f"wall={rec['wall_speedup']}x mem={rec['peak_mem_ratio']}x "
         f"over {rec['config']['jobs']}x{rec['config']['sites']}",
     )
-    rec["equivalence"] = [bench_sim_equivalence(256, 16)]
     return rec
 
 
@@ -207,25 +138,15 @@ if __name__ == "__main__":
     ap.add_argument("--sites", type=int, default=10_000)
     ap.add_argument("--tiers", type=int, default=100)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--skip-equivalence", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="toy-size bit-identity gate; no JSON written")
     args = ap.parse_args()
     if args.smoke:
         rec = bench_scale(jobs=2_000, sites=64, tiers_n=4, seed=args.seed)
         print("BENCH " + json.dumps(rec))
-        eq = bench_sim_equivalence(32, 4, seed=args.seed)
-        print("BENCH " + json.dumps(eq))
         raise SystemExit(0)
     rec = bench_scale(args.jobs, args.sites, args.tiers, args.seed)
     print("BENCH " + json.dumps(rec))
-    if not args.skip_equivalence:
-        rec["equivalence"] = [
-            bench_sim_equivalence(256, 16),
-            bench_sim_equivalence(1_000, 50),
-        ]
-        for e in rec["equivalence"]:
-            print("BENCH " + json.dumps(e))
     out = pathlib.Path(__file__).resolve().parent.parent / "BENCH_hier.json"
     out.write_text(json.dumps({"rows": [], "result": rec}, indent=2) + "\n")
     print(f"wrote {out}")

@@ -162,12 +162,7 @@ for wire in ("full", "delta"):
 # The same protocol drives the simulator at scale: see
 # repro.sim.P2PGridSim (gossip_wire=/gossip_quant=) and
 # benchmarks/p2p_bench.py (bytes + makespan, compressed vs
-# uncompressed, as a function of exchange interval). With a
-# GridTopology attached, GossipExchange(summaries=True) (or
-# SimConfig(gossip_summaries=True)) additionally gossips one TierSummary
-# row per RootGrid tier — min/max aggregates of the tier's §IV terms —
-# so at 10k+ sites a peer can bound whole tiers it has never received a
-# full pack row for (§11 below).
+# uncompressed, as a function of exchange interval).
 
 # --- 8. event-horizon streaming: one SimConfig, lazy ArrivalSources -------
 # Every simulator knob lives in SimConfig now (the old keyword style
@@ -309,17 +304,3 @@ hier_batch = hier_sched.place_batch(
 assert hier_batch.sites == batch.sites    # bit-identical to §5's flat pass
 print(f"\nhier placement (2 tiers): identical to flat on "
       f"{len(hier_batch.sites)} jobs")
-
-# The simulators take the same switch: SimConfig(placement="hier",
-# topology=...) routes both run loops — batched arrivals AND the lazy
-# §IX migration pass — through the tier bounds, whole-trace identical
-# to placement="flat" (tests/sim/test_hier_sim.py pins this).
-sim_topo = GridTopology()
-for i, name in enumerate(paper_grid_spec()):
-    sim_topo.join(f"region{i % 2}", Node(name=name))
-cfg = SimConfig(policy="diana", placement="hier", topology=sim_topo,
-                migration_interval_s=60.0)
-res = GridSim(paper_grid_spec(), config=cfg).run(
-    bulk_burst("lisa", 200, work=150.0, input_bytes=1e9))
-print(f"hier GridSim run: {res.finished} finished, "
-      f"{res.migrations()} migrations")

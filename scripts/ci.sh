@@ -37,10 +37,9 @@ python benchmarks/p2p_bench.py --sites 16 --peers 3 --jobs 200 --chaos-smoke
 # every job with bounded in-flight state and zero retained per-job
 # records (asserts inside the bench; no JSON written).
 python benchmarks/streaming_bench.py --smoke
-# Hier-placement smoke (2k jobs × 64 sites / 4 tiers + a 32-site sim
-# pin): two-level tier-summary placement must stay bit-identical to the
-# flat dense argmin, in place_batch and across a full GridSim/P2P event
-# stream (asserts inside the bench; no JSON written).
+# Hier-placement smoke (2k jobs × 64 sites / 4 tiers): place_batch's
+# two-level tier-bound path must stay bit-identical to the flat dense
+# argmin (asserts inside the bench; no JSON written).
 python benchmarks/hier_bench.py --smoke
 # Scenario-pack smoke (4 scenarios, ~200 jobs × 16 sites each): every
 # generator × verifier pair end to end — fault plans interleaved into

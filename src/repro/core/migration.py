@@ -23,7 +23,6 @@ __all__ = [
     "MigrationDecision",
     "select_peer",
     "select_peer_targets",
-    "select_peer_targets_lazy",
     "select_peers_batch",
     "staleness_excluded",
     "migrate_congested",
@@ -153,77 +152,6 @@ def select_peer_targets(
     return migrate, best
 
 
-def _lazy_cost_argmin(
-    excluded: np.ndarray,
-    jobs_ahead: np.ndarray,
-    cost_cols: Callable[[np.ndarray], np.ndarray],
-) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """``_peer_argmin`` without a dense cost plane.
-
-    The §IX key is (jobs_ahead, total_cost)-lexicographic, so the cost
-    only ever breaks ties *within* the min-jobs-ahead candidate columns
-    — and ``jobs_ahead`` is cheap (searchsorted counts) while the §IV
-    cost plane is the expensive part. This evaluates ``cost_cols(cols)
-    -> (J, k)`` exactly once, on the union of candidate columns, and
-    leaves every other column untouched; the hierarchical migration
-    pass feeds it per-tier static slices. Results are bit-identical to
-    ``_peer_argmin`` over the fully materialized plane because
-    non-candidate costs are never read there either.
-    """
-    ja = np.where(excluded[None, :], np.inf, np.asarray(jobs_ahead, np.float64))
-    ja_min = ja.min(axis=1)
-    candidates = ja == ja_min[:, None]
-    need = np.nonzero(candidates.any(axis=0))[0]
-    cost = np.full(ja.shape, np.inf)
-    if need.size:
-        cost[:, need] = np.asarray(cost_cols(need), np.float64)
-        cost[:, need[excluded[need]]] = np.inf
-    cost_cand = np.where(candidates, cost, np.inf)
-    best = np.argmin(cost_cand, axis=1)
-    rows = np.arange(ja.shape[0])
-    miss = ~candidates[rows, best]
-    if miss.any():
-        best[miss] = np.argmax(candidates[miss], axis=1)
-    return ja_min, best, cost[rows, best]
-
-
-def select_peer_targets_lazy(
-    pinned: np.ndarray,
-    local_jobs_ahead: np.ndarray,
-    local_cost: np.ndarray,
-    excluded: np.ndarray,
-    jobs_ahead: np.ndarray,
-    cost_cols: Callable[[np.ndarray], np.ndarray],
-    staleness: Optional[np.ndarray] = None,
-    max_staleness: float = float("inf"),
-) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """``select_peer_targets`` with the cost plane evaluated lazily on
-    the candidate columns only (see ``_lazy_cost_argmin``). Returns
-    ``(migrate, best, best_cost)`` — the extra best-cost column lets
-    callers reconstruct the sequential reason strings without the
-    plane. Decisions are bit-identical to the dense path."""
-    ja = np.asarray(jobs_ahead, np.float64)
-    if ja.ndim != 2:
-        if ja.size == 0:
-            return np.zeros(0, bool), np.zeros(0, np.int64), np.zeros(0)
-        raise ValueError(f"jobs_ahead must be a (J, S) plane, got shape {ja.shape}")
-    J = ja.shape[0]
-    if J == 0:
-        return np.zeros(0, bool), np.zeros(0, np.int64), np.zeros(0)
-    excluded = staleness_excluded(excluded, staleness, max_staleness)
-    if excluded.all():
-        return np.zeros(J, bool), np.zeros(J, np.int64), np.full(J, np.inf)
-    ja_min, best, best_cost = _lazy_cost_argmin(excluded, ja, cost_cols)
-    lja = np.asarray(local_jobs_ahead, np.float64)
-    lcost = np.asarray(local_cost, np.float64)
-    migrate = (
-        ~np.asarray(pinned, bool)
-        & (ja_min < lja)
-        & ((best_cost <= lcost) | (best_cost < np.inf))
-    )
-    return migrate, best, best_cost
-
-
 def select_peers_batch(
     jobs: Sequence[Job],
     local_name: str,
@@ -231,11 +159,10 @@ def select_peers_batch(
     local_cost: np.ndarray,
     names: Sequence[str],
     jobs_ahead: np.ndarray,
-    total_cost: Optional[np.ndarray] = None,
+    total_cost: np.ndarray,
     alive: Optional[np.ndarray] = None,
     staleness: Optional[np.ndarray] = None,
     max_staleness: float = float("inf"),
-    cost_cols: Optional[Callable[[np.ndarray], np.ndarray]] = None,
 ) -> list[MigrationDecision]:
     """Vectorized ``select_peer`` over a (J, S) peer grid.
 
@@ -251,15 +178,8 @@ def select_peers_batch(
     An empty candidate set (J=0) returns an empty decision list.
     Without staleness, decisions — targets and reason strings — are
     identical to ``[select_peer(j, local_name, lja, lc, peers) ...]``.
-
-    Passing ``cost_cols`` instead of ``total_cost`` switches to the
-    lazy candidate-column evaluation of ``select_peer_targets_lazy``
-    (decisions and reason strings stay identical).
     """
-    if cost_cols is not None and total_cost is None:
-        tc = np.asarray(jobs_ahead, np.float64)
-    else:
-        tc = np.asarray(total_cost, np.float64)
+    tc = np.asarray(total_cost, np.float64)
     if tc.ndim != 2:
         if tc.size == 0 and len(jobs) == 0:
             return []
@@ -286,10 +206,7 @@ def select_peers_batch(
             else MigrationDecision(False, reason=no_peer)
             for j in jobs
         ]
-    if cost_cols is not None and total_cost is None:
-        ja_min, best, best_cost = _lazy_cost_argmin(excluded, jobs_ahead, cost_cols)
-    else:
-        ja_min, best, best_cost = _peer_argmin(excluded, jobs_ahead, total_cost)
+    ja_min, best, best_cost = _peer_argmin(excluded, jobs_ahead, total_cost)
     lja = np.asarray(local_jobs_ahead, np.float64)
     lcost = np.asarray(local_cost, np.float64)
     decisions: list[MigrationDecision] = []

@@ -33,7 +33,7 @@ On a reliable transport with a delay the delta wire is simulated a round
 at a time: a round's packets, and later their acks, are one heap entry
 each, applied as arrays over the peers' stacked views, with the effects
 and in the order of the per-packet path (which the faulty transport,
-zero latency, tier summaries and the full wire keep).
+zero latency and the full wire keep).
 
 Delivery latency models the WAN: adverts sent at t arrive at
 t+latency, so a receiver's ``staleness`` of a remote row is
@@ -78,7 +78,6 @@ from .batch import (
     PACK_FIELDS,
     JobPack,
     SitePack,
-    TierPack,
     merge_packed_rows,
 )
 from .bulk import BulkGroup, BulkScheduler, GroupPlacement
@@ -92,13 +91,11 @@ __all__ = [
     "OWNER_FIELDS",
     "QUANT_FIELDS",
     "SiteAdvert",
-    "TierSummary",
     "ExchangeStats",
     "PeerScheduler",
     "GossipExchange",
     "single_peer",
     "advert_wire_bytes",
-    "summary_wire_bytes",
     "encode_packet",
     "decode_packet",
     "PacketError",
@@ -140,34 +137,6 @@ def advert_wire_bytes(advert: SiteAdvert) -> int:
     return 8 * 8 + 8 + 8 + 8 + 1 + len(advert.site)
 
 
-@dataclass(frozen=True)
-class TierSummary:
-    """One RootGrid tier's aggregate row (two-level gossip).
-
-    At scale a peer doesn't need dense rows for every remote tier to
-    know whether that tier could ever win a placement — the admissible
-    per-component extrema (the same aggregates ``TierPack`` prunes
-    with) are enough. Cross-tier gossip ships one of these per tier
-    instead of one row per site; dense rows keep flowing within a
-    tier. Last-writer-wins by the owner's ``stamp``.
-    """
-
-    tier: str
-    stamp: float               # owner clock at aggregation
-    n: int                     # member sites
-    n_alive: int
-    net_min: float             # min member network cost
-    eff_max: float             # max member effective bandwidth
-    cap_max: float             # max member capacity
-    comp_min: float            # min member job-independent comp term
-
-
-def summary_wire_bytes(summary: TierSummary) -> int:
-    """Serialized size of one tier summary: stamp + 4 aggregate f64 +
-    two u16 counts + tier name."""
-    return 8 + 4 * 8 + 2 + 2 + len(summary.tier)
-
-
 @dataclass
 class ExchangeStats:
     """Counters for the exchange cost the p2p bench reports.
@@ -188,8 +157,6 @@ class ExchangeStats:
     heartbeats_sent: int = 0
     acks_sent: int = 0
     full_syncs: int = 0
-    #: tier summary rows sent (two-level gossip; 0 with summaries off)
-    summaries_sent: int = 0
     # -- unreliable-transport counters (zero on a reliable transport) ----
     #: messages the fault model dropped in flight (packets and acks)
     dropped: int = 0
@@ -217,7 +184,6 @@ class ExchangeStats:
             "heartbeats_sent": self.heartbeats_sent,
             "acks_sent": self.acks_sent,
             "full_syncs": self.full_syncs,
-            "summaries_sent": self.summaries_sent,
             "dropped": self.dropped,
             "duplicated": self.duplicated,
             "corrupted": self.corrupted,
@@ -488,17 +454,6 @@ class PeerScheduler:
         # the whole home partition, the default); a set = only the named
         # home sites have changed since the last refresh.
         self._home_dirty: Optional[set] = None
-        # Two-level placement cache (mode="hier"): the TierPack over the
-        # world view, refreshed narrowly — only columns whose gossip
-        # epoch moved since the last build can have changed their static
-        # fields (speculation touches queue/work only, which TierPack
-        # reads live from the view).
-        self._tp: Optional[TierPack] = None
-        self._tp_tiers = None
-        self._tp_version: Optional[np.ndarray] = None
-        # Remote RootGrid aggregates received via tier-summary gossip
-        # (tier label → freshest TierSummary, last-writer-wins by stamp).
-        self.tier_summaries: dict[str, TierSummary] = {}
         # With tracking on: home sites this peer's own placements wrote
         # (``_commit_home``) since the last stamped refresh, which
         # re-measures them; and whether any home content was re-read or
@@ -817,72 +772,7 @@ class PeerScheduler:
             self._dirty[cols[applied]] = False  # owner truth replaces speculation
         return int(applied.sum())
 
-    # -- tier summaries (two-level gossip) --------------------------------------
-    def tier_summary(
-        self,
-        tier: str,
-        member_sites: Sequence[str],
-        now: float = 0.0,
-    ) -> TierSummary:
-        """Aggregate this peer's view of one tier into a ``TierSummary``
-        (the sender's own tier: home columns are authoritative and
-        in-tier columns refresh densely, so the aggregates are fresh)."""
-        cols = np.asarray(
-            [self._col[n] for n in member_sites if n in self._col], np.int64
-        )
-        if cols.size == 0:
-            raise ValueError(f"tier {tier!r} has no known member sites")
-        v = self.view
-        loss, bw = v.loss[cols], v.bw[cols]
-        net = (loss / bw) * 1.0e6
-        with np.errstate(divide="ignore", invalid="ignore"):
-            mathis = v.mss[cols] / (v.rtt[cols] * np.sqrt(loss))
-        eff = np.where(loss > 0.0, np.minimum(bw, mathis), bw)
-        w = self.weights
-        comp = (
-            w.w_queue * v.queue[cols] / v.cap[cols]
-            + w.w_work * v.work[cols] / v.cap[cols]
-            + w.w_load * v.load[cols]
-        )
-        return TierSummary(
-            tier=tier,
-            stamp=float(now),
-            n=int(cols.size),
-            n_alive=int(v.alive[cols].sum()),
-            net_min=float(net.min()),
-            eff_max=float(eff.max()),
-            cap_max=float(v.cap[cols].max()),
-            comp_min=float(comp.min()),
-        )
-
-    def receive_tier_summaries(self, summaries: Sequence[TierSummary]) -> int:
-        """Merge received tier summary rows, last-writer-wins by the
-        owner stamp; returns the number applied."""
-        applied = 0
-        for s in summaries:
-            cur = self.tier_summaries.get(s.tier)
-            if cur is None or s.stamp > cur.stamp:
-                self.tier_summaries[s.tier] = s
-                applied += 1
-        return applied
-
     # -- placement over the world view -----------------------------------------
-    def _tier_pack(self, tiers) -> TierPack:
-        """The cached two-level summary structure over the world view,
-        narrowed-refresh on gossip epoch changes (only a merge can move
-        a remote column's static fields, and every merge bumps the
-        column's version)."""
-        if self._tp is None or self._tp_tiers is not tiers:
-            self._tp = TierPack.from_site_pack(self.view, tiers)
-            self._tp_tiers = tiers
-            self._tp_version = self.version.copy()
-        else:
-            changed = np.flatnonzero(self.version != self._tp_version)
-            if changed.size:
-                self._tp.refresh(self.view, changed)
-                self._tp_version[changed] = self.version[changed]
-        return self._tp
-
     def rank_sites_batch(
         self,
         jobs: Sequence[Job],
@@ -897,26 +787,15 @@ class PeerScheduler:
         jobs: Sequence[Job],
         job_classes: Optional[Sequence[Optional[JobClass]]] = None,
         now: Optional[float] = None,
-        *,
-        mode: str = "flat",
-        tiers=None,
     ):
         self.refresh_home(now)
-        jp = self.engine.pack_jobs(jobs, job_classes)
-        if mode == "hier":
-            return self.engine.select_hier(jp, self.view, self._tier_pack(tiers))
-        if mode != "flat":
-            raise ValueError(f"mode must be 'flat' or 'hier', got {mode!r}")
-        return self.engine.select(jp, self.view)
+        return self.engine.select(self.engine.pack_jobs(jobs, job_classes), self.view)
 
     def place_batch(
         self,
         jobs: Sequence[Job],
         job_classes: Optional[Sequence[Optional[JobClass]]] = None,
         now: Optional[float] = None,
-        *,
-        mode: str = "flat",
-        tiers=None,
     ):
         """Batched §V placement against the (possibly stale) world view.
 
@@ -925,19 +804,10 @@ class PeerScheduler:
         calculate the cost to submit the next job", per peer); home
         columns are committed back to the authoritative ``SiteState``.
         With every site home, this is bit-identical to
-        ``DianaScheduler.place_batch``. ``mode="hier"`` resolves each
-        row through the two-level tier bounds (bit-identical decisions;
-        ``tiers`` is a dict / ``GridTopology`` / None as in
-        ``TierPack.from_site_pack``).
+        ``DianaScheduler.place_batch``.
         """
         self.refresh_home(now)
-        jp = JobPack.from_jobs(jobs, job_classes)
-        if mode == "hier":
-            placement = self.engine.replay_hier(jp, self.view, self._tier_pack(tiers))
-        elif mode == "flat":
-            placement = self.engine.replay(jp, self.view)
-        else:
-            raise ValueError(f"mode must be 'flat' or 'hier', got {mode!r}")
+        placement = self.engine.replay(JobPack.from_jobs(jobs, job_classes), self.view)
         for job, name in zip(jobs, placement.sites):
             job.site = name
         for c in set(int(i) for i in placement.site_indices):
@@ -1351,7 +1221,6 @@ class GossipExchange:
         quant: str = "f32",
         full_sync_every: int = 32,
         transport=None,
-        summaries: bool = False,
     ):
         if wire not in ("delta", "full"):
             raise ValueError(f"wire must be 'delta' or 'full', got {wire!r}")
@@ -1420,7 +1289,7 @@ class GossipExchange:
         # and so do its view's advertised fields and free slots.
         self._batched = (
             shared and wire == "delta" and transport is None
-            and not summaries and self.latency_s > 0.0
+            and self.latency_s > 0.0
         )
         if self._batched:
             peers = self.peers
@@ -1456,17 +1325,6 @@ class GossipExchange:
             i: gi for gi, g in enumerate(self._groups) for i in g
         }
         self._set_owner_suppression()
-        # Tier-summary gossip: cross-tier sends carry one aggregate row
-        # per tier instead of dense per-site rows (an at-scale
-        # approximation — remote tiers' dense rows stop refreshing).
-        self.summaries = bool(summaries)
-        self._peer_tier = [self._rootgrid_of(p.home) for p in self.peers]
-        if self.summaries:
-            names = list(self.peers[0].view.names) if self.peers else []
-            if self.topology is not None:
-                self._tier_sites = self.topology.tier_members(names)
-            else:
-                self._tier_sites = {"mesh": names}
 
     # -- hierarchy-aware fan-out ----------------------------------------------
     def _rootgrid_of(self, home: str) -> str:
@@ -1697,7 +1555,7 @@ class GossipExchange:
             pl = payload
             if t is not None and kind == "packet":
                 pl = self._maybe_corrupt(pl)
-            elif t is not None and kind in ("adverts", "summaries") and t.corrupt > 0.0:
+            elif t is not None and kind == "adverts" and t.corrupt > 0.0:
                 # Object payload (no bytes to flip): a corrupted
                 # full-wire datagram fails its checksum on arrival and
                 # is discarded whole; the next round re-floods it.
@@ -1712,10 +1570,6 @@ class GossipExchange:
                     self._heard(j, i, now)
                     self.stats.adverts_applied += self.peers[j].receive(pl)
                     self.stats.deliveries += 1
-                elif kind == "summaries":
-                    self._heard(j, i, now)
-                    self.peers[j].receive_tier_summaries(pl)
-                    self.stats.deliveries += 1
                 else:  # "ack"
                     self._apply_ack(pl)
                 continue
@@ -1726,7 +1580,7 @@ class GossipExchange:
             )
             if kind == "packet":
                 hp: object = (i, seq_key, pl)
-            elif kind in ("adverts", "summaries"):
+            elif kind == "adverts":
                 hp = (i, pl)
             else:
                 hp = pl
@@ -1940,13 +1794,6 @@ class GossipExchange:
                 self.stats.deliveries += 1
                 self.stats.adverts_applied += got
                 applied += got
-            elif kind == "summaries":
-                sender, rows = payload
-                if not self._active[j]:
-                    continue
-                self._heard(j, sender, due)
-                self.peers[j].receive_tier_summaries(rows)
-                self.stats.deliveries += 1
             elif kind == "packet":
                 sender, pseq, buf = payload
                 if not (self._active[j] and self._active[sender]):
@@ -1996,44 +1843,18 @@ class GossipExchange:
             targets = self.neighbors(i, self.stats.rounds)
             if not targets:
                 continue
-            summary_rows = (
-                self._summaries_payload(i, now) if self.summaries else None
-            )
             adverts = None
             size = 0
             for j in targets:
-                # With summaries on, cross-tier sends carry ONLY the
-                # O(tiers) summary rows; dense per-site payloads travel
-                # hierarchy-locally (and summaries ride along there too,
-                # so non-representative members hear about remote tiers).
-                dense = not (
-                    self.summaries and self._group_of[i] != self._group_of[j]
-                )
-                if dense:
-                    if self.wire == "delta":
-                        self._send_delta(i, j, now)
-                    else:
-                        if adverts is None:
-                            adverts = p.adverts()
-                            size = sum(advert_wire_bytes(a) for a in adverts)
-                        self.stats.adverts_sent += len(adverts)
-                        self.stats.bytes_sent += size
-                        self._send_message(now, i, j, "adverts", adverts)
-                if summary_rows is not None:
-                    self.stats.summaries_sent += len(summary_rows)
-                    self.stats.bytes_sent += sum(
-                        summary_wire_bytes(s) for s in summary_rows
-                    )
-                    self._send_message(now, i, j, "summaries", summary_rows)
-
-    def _summaries_payload(self, i: int, now: float) -> list[TierSummary]:
-        """Sender ``i``'s summary rows: its own tier re-aggregated
-        fresh, plus every remote tier row it has heard (relay gossip)."""
-        p = self.peers[i]
-        lab = self._peer_tier[i]
-        own = p.tier_summary(lab, self._tier_sites.get(lab, [p.home]), now)
-        p.receive_tier_summaries([own])
-        return list(p.tier_summaries.values())
+                if self.wire == "delta":
+                    self._send_delta(i, j, now)
+                else:
+                    if adverts is None:
+                        adverts = p.adverts()
+                        size = sum(advert_wire_bytes(a) for a in adverts)
+                    self.stats.adverts_sent += len(adverts)
+                    self.stats.bytes_sent += size
+                    self._send_message(now, i, j, "adverts", adverts)
 
     # -- delta wire ------------------------------------------------------------
     def _send_delta(self, i: int, j: int, now: float) -> None:

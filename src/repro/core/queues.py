@@ -400,6 +400,23 @@ class MultilevelFeedbackQueues:
         """§X: only low-priority (Q4) jobs are migration candidates."""
         return [j for j in self.jobs if j.queue == prio.NUM_QUEUES - 1]
 
+    # -- copying --------------------------------------------------------------
+    def __getstate__(self) -> dict:
+        """The id()-keyed maps travel as lists, so that a copy keys them
+        by the ids of its own jobs; ``jobs`` is rebuilt as a view."""
+        state = dict(self.__dict__)
+        del state["jobs"]
+        for k in ("_jobs", "_entry", "_loose"):
+            state[k] = list(state[k].values())
+        return state
+
+    def __setstate__(self, state: dict) -> None:
+        self.__dict__.update(state)
+        self._jobs = {id(j): j for j in state["_jobs"]}
+        self._entry = {id(e[3]): e for e in state["_entry"]}
+        self._loose = {id(e[3]): e for e in state["_loose"]}
+        self.jobs = self._jobs.values()
+
     # -- rates / congestion ---------------------------------------------------
     def prune_rate_samples(self, cutoff: float) -> None:
         """Discard rate samples strictly older than ``cutoff``. Only

@@ -72,15 +72,9 @@ class SimConfig:
     #: stream identically by both run loops. None = the classic
     #: always-alive grid.
     fault_plan: Optional["FaultPlan"] = None
-    #: Placement evaluation path for the diana policy: ``"flat"`` scans
-    #: every site per decision; ``"hier"`` runs the two-level tier-bound
-    #: argmin (tiers = ``topology`` RootGrids, or one tier without a
-    #: topology) — decisions are bit-identical, the dense pass just
-    #: shrinks to the winning tier(s).
-    placement: str = "flat"
     #: RootGrid/SubGrid control-plane topology. ``P2PGridSim`` uses it
-    #: for hierarchical gossip fan-out; both simulators use it as the
-    #: tier structure when ``placement="hier"``.
+    #: for the hierarchy-aware gossip fan-out (and the relay hops of a
+    #: cross-tier row's age); ``GridSim`` places over every site alike.
     topology: Optional[GridTopology] = None
 
     # -- P2PGridSim only --------------------------------------------------
@@ -106,13 +100,6 @@ class SimConfig:
     #: windows. None (or an all-zero model) = the classic perfectly
     #: reliable transport.
     transport_faults: Optional["TransportFaults"] = None
-    #: Gossip tier summaries (requires ``topology``): cross-tier rounds
-    #: send one summary row per RootGrid instead of dense per-site
-    #: rows (dense rows still flow within a tier). Shrinks cross-tier
-    #: gossip from O(sites) to O(tiers) — an at-scale approximation:
-    #: cross-tier dense rows stop refreshing, so placement is NOT
-    #: bit-identical to dense gossip.
-    gossip_summaries: bool = False
 
     def replace(self, **kw) -> "SimConfig":
         return dataclasses.replace(self, **kw)
@@ -122,7 +109,7 @@ _P2P_FIELDS = frozenset({
     "peer_sites", "num_peers", "exchange_interval_s", "exchange_latency_s",
     "migration_max_staleness_s", "gossip_fanout",
     "gossip_wire", "gossip_quant", "gossip_full_sync_every",
-    "transport_faults", "gossip_summaries",
+    "transport_faults",
 })
 _ALL_FIELDS = frozenset(f.name for f in dataclasses.fields(SimConfig))
 _BASE_FIELDS = _ALL_FIELDS - _P2P_FIELDS

@@ -46,7 +46,6 @@ from repro.core.migration import (
     MigrationDecision,
     apply_migration,
     select_peer_targets,
-    select_peer_targets_lazy,
 )
 from repro.core.p2p import GossipExchange, PeerScheduler
 from repro.core.topology import GridTopology
@@ -219,10 +218,6 @@ class GridSim:
     ):
         cfg = resolve_config(config, kw, self._LEGACY_FIELDS, type(self).__name__)
         assert cfg.policy in ("diana", "greedy", "local", "fcfs")
-        if cfg.placement not in ("flat", "hier"):
-            raise ValueError(
-                f"placement must be 'flat' or 'hier', got {cfg.placement!r}"
-            )
         self.config = cfg
         policy = self.policy = cfg.policy
         self._loss: Optional[np.ndarray] = None  # built on first batch
@@ -317,16 +312,6 @@ class GridSim:
         self._bw = self._eff = None
         self._static_row_cache.clear()
         self._dense_failed = False
-        # The two-level placement aggregates are derived from the same
-        # dense matrices, so they fall with them (rebuilt lazily).
-        self._h_perm = None
-        self._h_starts = None
-        self._h_tier_cols = None
-        self._h_tier_of = None
-        self._h_net_tmin = None
-        self._h_effin_tmax = None
-        self._h_effout_tmax = None
-        self._h_ok = False
         # Re-enable the arrival fast path only if the old table's
         # partialness disabled it (never override a user's own setting).
         if getattr(self, "_batch_arrivals_auto_disabled", False):
@@ -553,136 +538,6 @@ class GridSim:
             out = np.where(self._alive_vec, out, np.inf)
         return out
 
-    # -- two-level placement (config.placement == "hier") ---------------------
-    def _hier_ready(self) -> bool:
-        """True when the two-level tier-bound pick may replace the flat
-        row argmin: hier placement requested, diana policy, dense WAN
-        matrices available, and the tier aggregates built (lazily) from
-        a sane table (finite network terms, positive effective
-        bandwidths — the preconditions of the bound algebra)."""
-        if self.config.placement != "hier" or self.policy != "diana":
-            return False
-        if not self._link_matrices_ready():
-            return False
-        if self._h_perm is None:
-            self._build_hier_structs()
-        return self._h_ok
-
-    def _build_hier_structs(self) -> None:
-        """Static per-origin tier aggregates over the dense matrices.
-
-        One tier = one RootGrid of ``config.topology`` (no topology =
-        one tier over the whole grid; off-topology sites become
-        singleton tiers via ``tier_of``). Per origin (and per data
-        site) the aggregates give admissible §IV lower bounds:
-
-          net_tmin[o, t]     min over s∈t of the network term from o
-          effin_tmax[d, t]   max over s∈t of eff(d→s): divides into a
-                             lower bound on the input-fetch term
-          effout_tmax[o, t]  max over s∈t of eff(s→o): same for the
-                             output-return term
-
-        Members within a tier are kept in ascending sorted-column
-        order, so a within-tier argmin's first-index tie-break is the
-        lowest global column of that tier — the cross-tier (cost, col)
-        walk in ``_hier_pick`` then reproduces the flat argmin's
-        global first-index tie-break exactly."""
-        names = self._names_sorted
-        topo = self.config.topology
-        if topo is not None:
-            members = topo.tier_members(names)
-        else:
-            members = {"grid": list(names)}
-        labels = sorted(members)
-        idx = self._site_idx
-        perm = np.asarray(
-            [idx[n] for lab in labels for n in members[lab]], np.int64
-        )
-        sizes = [len(members[lab]) for lab in labels]
-        starts = np.cumsum([0] + sizes[:-1], dtype=np.int64)
-        self._h_perm = perm
-        self._h_starts = starts
-        self._h_tier_cols = [
-            np.asarray([idx[n] for n in members[lab]], np.int64)
-            for lab in labels
-        ]
-        tier_of = np.empty(len(names), np.int64)
-        for t, cols in enumerate(self._h_tier_cols):
-            tier_of[cols] = t
-        self._h_tier_of = tier_of
-        net_all = (self._loss / self._bw) * 1.0e6      # net[o, s]
-        self._h_net_tmin = np.minimum.reduceat(net_all[:, perm], starts, axis=1)
-        self._h_effin_tmax = np.maximum.reduceat(self._eff[:, perm], starts, axis=1)
-        self._h_effout_tmax = np.maximum.reduceat(self._eff.T[:, perm], starts, axis=1)
-        # Bound admissibility needs finite network terms and positive
-        # effective bandwidths (division by a tier-max is only a lower
-        # bound for a positive, monotone divisor). A degenerate table
-        # keeps hier off and the flat path bit-exact by construction.
-        self._h_ok = bool(
-            np.isfinite(net_all).all() and (self._eff > 0.0).all()
-        )
-
-    def _hier_pick(self, sj: SimJob, comp: np.ndarray,
-                   net_row: np.ndarray, dtc_row: np.ndarray) -> int:
-        """Two-level argmin over one job's §IV row — bit-identical to
-        ``int(np.argmin((net_row + comp) + dtc_row))``.
-
-        Tiers are ranked by an admissible lower bound (each §IV term
-        bounded independently; fp addition is monotone, and a relative
-        round-down guard absorbs the bound's own rounding), then the
-        exact row is evaluated only on tiers whose bound can still beat
-        the best cost seen. Ties widen: a tier whose bound *equals* the
-        current best is still refined, and the (cost, column) walk
-        keeps the lowest column among equal minima — the flat argmin's
-        first-index rule across tier boundaries."""
-        inb, outb = sj.input_bytes, sj.output_bytes
-        if not (inb >= 0.0 and outb >= 0.0):
-            # Negative/NaN byte counts break the division-monotonicity
-            # argument; the degenerate flat row is the spec.
-            return int(np.argmin((net_row + comp) + dtc_row))
-        o = self._site_idx[sj.origin_site]
-        T = len(self._h_tier_cols)
-        comp_tmin = np.minimum.reduceat(comp[self._h_perm], self._h_starts)
-        if sj.data_site is not None and inb > 0.0:
-            d = self._site_idx[sj.data_site]
-            in_lb = inb / self._h_effin_tmax[d]
-            in_lb[self._h_tier_of[d]] = 0.0     # s == data site ⇒ no fetch
-        else:
-            in_lb = np.zeros(T)
-        if outb > 0.0:
-            out_lb = outb / self._h_effout_tmax[o]
-            out_lb[self._h_tier_of[o]] = 0.0    # s == origin ⇒ no return
-        else:
-            out_lb = np.zeros(T)
-        bound = (self._h_net_tmin[o] + comp_tmin) + (in_lb + out_lb)
-        bad = np.isnan(bound)
-        if bad.any():
-            bound[bad] = -np.inf                # unknown ⇒ always refine
-        fin = np.isfinite(bound)
-        bound[fin] -= np.abs(bound[fin]) * 1e-12
-        best_cost = np.inf
-        best_col = -1
-        for t in np.argsort(bound, kind="stable"):
-            if bound[t] > best_cost:
-                break
-            cols = self._h_tier_cols[t]
-            row = (net_row[cols] + comp[cols]) + dtc_row[cols]
-            k = int(np.argmin(row))
-            c = row[k]
-            if np.isnan(c):
-                # A NaN row entry hijacks np.argmin in the flat path;
-                # reproduce that verdict exactly via the full row.
-                return int(np.argmin((net_row + comp) + dtc_row))
-            col = int(cols[k])
-            if c < best_cost or (c == best_cost and col < best_col):
-                best_cost = c
-                best_col = col
-        if best_col < 0:
-            # Every tier refined to +inf (all sites poisoned): the flat
-            # argmin of an all-inf row answers column 0.
-            return int(np.argmin((net_row + comp) + dtc_row))
-        return best_col
-
     def choose_sites_batch(self, batch: list[SimJob]) -> list[str]:
         """Vectorized ``choose_site`` over a batch against the current
         state snapshot (no admissions in between) — equivalent to
@@ -702,13 +557,6 @@ class GridSim:
         if self._dead:
             base = np.where(self._alive_vec, base, np.inf)
         cap = np.asarray([float(self.sites[n].nodes) for n in self._names_sorted])
-        if self._hier_ready():
-            return [
-                self._names_sorted[
-                    self._hier_pick(sj, base + sj.work / cap, net[i], dtc[i])
-                ]
-                for i, sj in enumerate(batch)
-            ]
         return [
             self._names_sorted[int(np.argmin((net[i] + (base + sj.work / cap)) + dtc[i]))]
             for i, sj in enumerate(batch)
@@ -1004,11 +852,6 @@ class GridSim:
         re-read from live site state, so placements are bit-identical
         to sequential ``_on_arrive`` calls."""
         net, dtc = self._static_cost_rows(batch)
-        if self._hier_ready():
-            for i, sj in enumerate(batch):
-                k = self._hier_pick(sj, self._comp_vec(sj), net[i], dtc[i])
-                self._admit(sj, self._names_sorted[k], now, events)
-            return
         for i, sj in enumerate(batch):
             row = (net[i] + self._comp_vec(sj)) + dtc[i]
             self._admit(sj, self._names_sorted[int(np.argmin(row))], now, events)
@@ -1448,9 +1291,6 @@ class GridSim:
         sites (source and target), so only those two columns are
         re-read and the remaining rows re-decided — every decision is
         bit-identical to the sequential per-job loop."""
-        if self.config.placement == "hier":
-            self._migrate_site_lazy(name, site, cands, sjs, sp, now, events)
-            return
         R = len(cands)
         perm = self._dict_perm
         names = self._dict_names
@@ -1510,113 +1350,6 @@ class GridSim:
             migrate[rest], best[rest] = select_peer_targets(
                 pinned[rest], ja[rest, local_col], cost[rest, local_col],
                 excluded, ja[rest], cost[rest],
-                staleness=stale_d, max_staleness=self.migration_max_staleness_s,
-            )
-
-    def _migrate_site_lazy(
-        self,
-        name: str,
-        site: _Site,
-        cands: list[Job],
-        sjs: list[SimJob],
-        sp: SitePack,
-        now: float,
-        events: list,
-    ) -> None:
-        """``_migrate_site_batched`` with the candidate × peer §IV cost
-        plane evaluated lazily (``placement="hier"``).
-
-        The §IX key is (jobsAhead, cost)-lexicographic, so the cost is
-        only ever read at min-jobsAhead candidate columns;
-        ``select_peer_targets_lazy`` asks for exactly those and this
-        pass materializes them column-by-column from the memoized
-        static planes. jobsAhead stays dense (searchsorted counts —
-        the cheap key). Decisions, reason strings and applied moves
-        are bit-identical to the dense pass: a lazily-computed column
-        is the same elementwise float program as its dense twin, and
-        columns recomputed after a move only differ at the two sites
-        the move actually touched."""
-        R = len(cands)
-        perm = self._dict_perm
-        names = self._dict_names
-        local_col = self._dict_pos[name]
-        jp = JobPack.from_jobs(cands)
-        work = jp.work                      # == [sj.work for sj in sjs]
-        cand_p = np.asarray([cj.priority for cj in cands], np.float64)
-        net, dtc = self._static_cost_rows(sjs)
-        net_d, dtc_d = net[:, perm], dtc[:, perm]
-        cap_d = sp.cap[perm]
-        S = len(names)
-        costm = np.empty((R, S))
-        have = np.zeros(S, bool)
-        comp_d = [comp_site_column(sp, self.weights)[perm]]
-
-        def _fill(cols: np.ndarray) -> None:
-            need = cols[~have[cols]]
-            if need.size:
-                # placement_cost's exact op order, sliced per column:
-                # (net + (comp_site + w/cap)) + dtc
-                costm[:, need] = (
-                    net_d[:, need]
-                    + (comp_d[0][need][None, :] + work[:, None] / cap_d[need][None, :])
-                ) + dtc_d[:, need]
-                have[need] = True
-
-        def _cost_rows(lo: int):
-            def cb(cols: np.ndarray) -> np.ndarray:
-                _fill(np.asarray(cols, np.int64))
-                return costm[lo:, cols]
-            return cb
-
-        _fill(np.asarray([local_col], np.int64))
-        ja = np.empty((R, S))
-        for s, pname in enumerate(names):
-            ja[:, s] = self._jobs_ahead_column(pname, cand_p)
-        pinned = np.asarray([cj.migrated for cj in cands], bool)
-        excluded = np.asarray(
-            [n == name or not self.sites[n].alive for n in names]
-        )
-        stale = self._migration_staleness(name, now)
-        stale_d = None if stale is None else stale[perm]
-        migrate, best, bcost = select_peer_targets_lazy(
-            pinned, ja[:, local_col], costm[:, local_col], excluded, ja,
-            _cost_rows(0),
-            staleness=stale_d, max_staleness=self.migration_max_staleness_s,
-        )
-        i = 0
-        while i < R:
-            rel = np.flatnonzero(migrate[i:])
-            if rel.size == 0:
-                break
-            i += int(rel[0])
-            c = int(best[i])
-            target = names[c]
-            d = MigrationDecision(
-                True, target=target,
-                reason="peer has fewer jobs ahead at lower cost"
-                if bcost[i] <= costm[i, local_col]
-                else "peer has fewer jobs ahead",
-            )
-            self._apply_migration_decision(name, site, cands[i], sjs[i], d, now, events)
-            # The move touched exactly {source, target}: re-read those
-            # two columns and re-decide the remaining candidates (the
-            # untouched cached columns recompute to identical floats).
-            self._resync_pack(sp, {name, target})
-            i += 1
-            if i >= R:
-                break
-            comp = comp_site_column(sp, self.weights)
-            comp_d[0] = comp[perm]
-            for tn in (name, target):
-                cd = self._dict_pos[tn]
-                sc = self._site_idx[tn]
-                costm[:, cd] = (net[:, sc] + (comp[sc] + work / sp.cap[sc])) + dtc[:, sc]
-                have[cd] = True
-                ja[:, cd] = self._jobs_ahead_column(tn, cand_p)
-            rest = slice(i, R)
-            migrate[rest], best[rest], bcost[rest] = select_peer_targets_lazy(
-                pinned[rest], ja[rest, local_col], costm[rest, local_col],
-                excluded, ja[rest], _cost_rows(i),
                 staleness=stale_d, max_staleness=self.migration_max_staleness_s,
             )
 
@@ -1765,7 +1498,6 @@ class P2PGridSim(GridSim):
             wire=cfg.gossip_wire, quant=cfg.gossip_quant,
             full_sync_every=cfg.gossip_full_sync_every,
             transport=cfg.transport_faults,
-            summaries=cfg.gossip_summaries,
         )
         # peer index → the home partition it held when it left (churn
         # faults); handed back verbatim on rejoin.
@@ -1859,13 +1591,6 @@ class P2PGridSim(GridSim):
         if not self._batch_eligible(batch):
             return [self.choose_site(sj) for sj in batch]
         net, dtc = self._static_cost_rows(batch)
-        if self._hier_ready():
-            return [
-                self._names_sorted[
-                    self._hier_pick(sj, self._comp_vec(sj), net[i], dtc[i])
-                ]
-                for i, sj in enumerate(batch)
-            ]
         return [
             self._names_sorted[int(np.argmin((net[i] + self._comp_vec(sj)) + dtc[i]))]
             for i, sj in enumerate(batch)

@@ -2,8 +2,7 @@
 
 The hierarchical path prunes with per-tier admissible lower bounds and
 f32 shortlist packs, then refines exactly — so every observable output
-(site choices, costs, queue/work feedback, migration reason strings)
-must be **bit-identical** to the flat dense argmin. These tests sweep
+(site choices, costs, queue/work feedback) must be **bit-identical** to the flat dense argmin. These tests sweep
 random topologies, tier skews and dirty-column refresh interleavings
 to enforce that contract.
 """
@@ -39,11 +38,6 @@ from repro.core.batch import (
     hier_replay,
     hier_select,
     replay_on_pack,
-)
-from repro.core.migration import (
-    select_peer_targets,
-    select_peer_targets_lazy,
-    select_peers_batch,
 )
 
 
@@ -351,69 +345,3 @@ class TestTierPackRefresh:
         hier = hier_select(jp, sp, tp, w)
         assert hier.sites == flat.sites
         assert list(hier.costs) == list(flat.costs)
-
-
-class TestLazyMigration:
-    @given(seed=st.integers(0, 100_000), n_jobs=st.integers(1, 25),
-           n_peers=st.integers(1, 20))
-    @settings(max_examples=40, deadline=None)
-    def test_lazy_targets_match_dense(self, seed, n_jobs, n_peers):
-        rng = np.random.default_rng(seed)
-        cost = rng.uniform(0, 100, (n_jobs, n_peers))
-        cost[rng.uniform(size=cost.shape) < 0.1] = np.inf
-        ja = rng.integers(0, 6, (n_jobs, n_peers)).astype(float)
-        lcost = rng.uniform(0, 100, n_jobs)
-        lja = rng.integers(0, 6, n_jobs).astype(float)
-        pinned = rng.uniform(size=n_jobs) < 0.2
-        excluded = rng.uniform(size=n_peers) < 0.3
-
-        touched = np.zeros(n_peers, bool)
-
-        def cost_cols(cols):
-            touched[cols] = True
-            return cost[:, cols]
-
-        if excluded.all():
-            m1, b1 = select_peer_targets(pinned, lja, lcost, excluded, ja, cost)
-            m2, b2, _ = select_peer_targets_lazy(
-                pinned, lja, lcost, excluded, ja, cost_cols)
-            np.testing.assert_array_equal(m1, m2)
-            return
-
-        m1, b1 = select_peer_targets(pinned, lja, lcost, excluded, ja, cost)
-        m2, b2, bc = select_peer_targets_lazy(
-            pinned, lja, lcost, excluded, ja, cost_cols)
-        np.testing.assert_array_equal(m1, m2)
-        np.testing.assert_array_equal(b1, b2)
-        rows = np.arange(n_jobs)
-        # best-cost column is exact wherever a migration fires
-        np.testing.assert_array_equal(bc[m2], cost[rows, b2][m2])
-        # laziness is real: only min-jobsAhead candidate columns read
-        ja_m = np.where(excluded[None, :], np.inf, ja)
-        cand = (ja_m == ja_m.min(axis=1)[:, None]).any(axis=0)
-        assert not touched[~cand].any()
-
-    @given(seed=st.integers(0, 100_000), n_jobs=st.integers(0, 20))
-    @settings(max_examples=25, deadline=None)
-    def test_select_peers_batch_lazy_reasons_match(self, seed, n_jobs):
-        """The decision-object surface: reason strings through the lazy
-        path must be character-identical to the dense path."""
-        rng = np.random.default_rng(seed)
-        n_peers = int(rng.integers(1, 12))
-        names = [f"p{i}" for i in range(n_peers)]
-        local = names[int(rng.integers(0, n_peers))]
-        cost = rng.uniform(0, 50, (n_jobs, n_peers))
-        ja = rng.integers(0, 4, (n_jobs, n_peers)).astype(float)
-        lcost = rng.uniform(0, 50, n_jobs)
-        lja = rng.integers(0, 4, n_jobs).astype(float)
-        alive = rng.uniform(size=n_peers) > 0.25
-        jobs = [Job(user="u", migrated=bool(rng.uniform() < 0.2))
-                for _ in range(n_jobs)]
-
-        dense = select_peers_batch(
-            jobs, local, lja, lcost, names, ja, cost, alive=alive)
-        lazy = select_peers_batch(
-            jobs, local, lja, lcost, names, ja, alive=alive,
-            cost_cols=lambda cols: cost[:, cols])
-        assert [(d.migrate, d.target, d.reason) for d in dense] == \
-               [(d.migrate, d.target, d.reason) for d in lazy]

@@ -1,5 +1,12 @@
 """§IX job migration + §X congestion-driven migration."""
+import numpy as np
 import pytest
+
+try:
+    from hypothesis import given, settings
+    from hypothesis import strategies as st
+except ImportError:                      # offline CI: vendored shim
+    from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core import (
     Job,
@@ -293,3 +300,56 @@ class TestSelectPeersBatch:
             assert d.migrate == bool(migrate[r]), r
             if d.migrate:
                 assert d.target == names[best[r]], r
+
+    @given(seed=st.integers(0, 100_000), n_jobs=st.integers(1, 25),
+           n_peers=st.integers(1, 20))
+    @settings(max_examples=40, deadline=None)
+    def test_targets_match_select_peer(self, seed, n_jobs, n_peers):
+        """The array core against per-job ``select_peer`` over infinite
+        costs, pinned rows and excluded columns."""
+        from repro.core.migration import select_peer_targets
+
+        rng = np.random.default_rng(seed)
+        names = [f"p{i}" for i in range(n_peers)]
+        cost = rng.uniform(0, 100, (n_jobs, n_peers))
+        cost[rng.uniform(size=cost.shape) < 0.1] = np.inf
+        ja = rng.integers(0, 6, (n_jobs, n_peers)).astype(float)
+        lcost = rng.uniform(0, 100, n_jobs)
+        lja = rng.integers(0, 6, n_jobs).astype(float)
+        pinned = rng.uniform(size=n_jobs) < 0.2
+        excluded = rng.uniform(size=n_peers) < 0.3
+        migrate, best = select_peer_targets(pinned, lja, lcost, excluded,
+                                            ja, cost)
+        for r in range(n_jobs):
+            peers = [
+                PeerView(name=n, queue_length=int(ja[r, s]),
+                         jobs_ahead=int(ja[r, s]), total_cost=cost[r, s],
+                         alive=not excluded[s])
+                for s, n in enumerate(names)
+            ]
+            ref = select_peer(Job(user="u", migrated=bool(pinned[r])),
+                              "local", lja[r], lcost[r], peers)
+            assert bool(migrate[r]) == ref.migrate, r
+            if ref.migrate:
+                assert names[best[r]] == ref.target, r
+
+    @given(seed=st.integers(0, 100_000), n_jobs=st.integers(0, 20))
+    @settings(max_examples=25, deadline=None)
+    def test_reasons_match_select_peer(self, seed, n_jobs):
+        """The decision objects, reason strings included, against
+        ``select_peer`` over infinite costs, pinned rows, dead peers and
+        the local column."""
+        rng = np.random.default_rng(seed)
+        n_peers = int(rng.integers(1, 12))
+        names = [f"p{i}" for i in range(n_peers)]
+        local = names[int(rng.integers(0, n_peers))]
+        cost = rng.uniform(0, 50, (n_jobs, n_peers))
+        cost[rng.uniform(size=cost.shape) < 0.1] = np.inf
+        ja = rng.integers(0, 4, (n_jobs, n_peers)).astype(float)
+        lcost = rng.uniform(0, 50, n_jobs)
+        lja = rng.integers(0, 4, n_jobs).astype(float)
+        alive = rng.uniform(size=n_peers) > 0.25
+        jobs = [Job(user="u", migrated=bool(rng.uniform() < 0.2))
+                for _ in range(n_jobs)]
+        self._assert_rows_match(jobs, local, lja, lcost, names, ja, cost,
+                                alive=alive)

@@ -311,3 +311,25 @@ def test_requeue_keeps_priority_and_goes_to_the_end():
         q.requeue(head)                      # already queued
     q.submit(Job(user="a", submit_time=9.0))
     assert head.user == "b" and head.priority == jobs[3].priority != kept[0]
+
+
+def test_a_deep_copy_queues_and_serves_like_the_original():
+    """A copied queue (a copied simulator holds them) keys its own jobs:
+    with classed and loose jobs queued, both copies pop, remove and
+    re-prioritize alike, and neither touches the other."""
+    import copy
+
+    q = MultilevelFeedbackQueues({"a": 1.0, "b": 4.0})
+    jobs = [q.submit(Job(user=u, t=1 + i % 2, submit_time=float(i)))
+            for i, u in enumerate("aabbab")]
+    jobs[1].priority = 0.99                  # loose until the next submit
+    q.requeue(q.pop_next())                  # loose, back at the end
+    c = copy.deepcopy(q)
+    assert [j.job_id for j in c.jobs] == [j.job_id for j in q.jobs]
+    mine = {j.job_id: j for j in c.jobs}
+    for queue, job in ((q, jobs[2]), (c, mine[jobs[2].job_id])):
+        queue.remove(job)
+        queue.submit(Job(user="a", submit_time=9.0, job_id=-1))
+    served = [[(j.job_id, j.priority, j.queue) for j in iter(s.pop_next, None)]
+              for s in (q, c)]
+    assert served[0] == served[1] and len(served[0]) == 6

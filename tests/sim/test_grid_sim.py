@@ -591,11 +591,24 @@ class TestBatchedMigration:
     def test_batched_is_default(self):
         assert GridSim(paper_grid_spec(), policy="diana").batch_migration
 
-    @pytest.mark.parametrize("interval,latency", [(30.0, 0.0), (60.0, 5.0)])
-    def test_p2p_staleness_equivalence(self, interval, latency):
+    @pytest.mark.parametrize(
+        "interval,latency,topology",
+        [(30.0, 0.0, False), (60.0, 5.0, False), (60.0, 5.0, True)],
+        ids=["30.0-0.0", "60.0-5.0", "60.0-5.0-topology"],
+    )
+    def test_p2p_staleness_equivalence(self, interval, latency, topology):
         """The batched migration pass must stay bit-identical to the
         per-job loop WITH the P2P staleness gating active: both paths
-        filter trusted peers from the same per-column stale vector."""
+        filter trusted peers from the same per-column stale vector
+        (under the hierarchy-aware fan-out too, whose relayed rows age
+        by more rounds)."""
+        from repro.core import GridTopology, Node
+
+        topo = None
+        if topology:
+            topo = GridTopology()
+            for i, n in enumerate(paper_grid_spec()):
+                topo.join(f"root{i % 2}", Node(name=n))
         jobs = _overload_workload()
         runs = []
         for batched in (False, True):
@@ -604,7 +617,7 @@ class TestBatchedMigration:
                              exchange_latency_s=latency,
                              batch_migration=batched, quotas=QUOTAS,
                              migration_interval_s=30.0,
-                             congestion_window_s=120.0)
+                             congestion_window_s=120.0, topology=topo)
             runs.append(sim.run(copy.deepcopy(jobs)))
         seq, bat = runs
         assert [j.exec_site for j in seq.jobs] == [j.exec_site for j in bat.jobs]
