@@ -34,6 +34,7 @@ __all__ = [
     "mathis_throughput",
     "network_cost",
     "computation_cost",
+    "computation_cost_of",
     "data_transfer_cost",
     "total_cost",
     "total_cost_matrix",
@@ -130,10 +131,21 @@ def computation_cost(
     site: SiteState, weights: CostWeights = CostWeights()
 ) -> float:
     """Paper §IV: W5·Qi/Pi + W6·Q/Pi + W7·SiteLoad."""
+    return computation_cost_of(
+        site.queue_length, site.waiting_work, site.load, site.capacity, weights
+    )
+
+
+def computation_cost_of(
+    queue_length: float, waiting_work: float, load: float, capacity: float,
+    weights: CostWeights,
+) -> float:
+    """``computation_cost`` from a site's numbers, for a caller that
+    keeps them without a ``SiteState``."""
     return (
-        weights.w_queue * site.queue_length / site.capacity
-        + weights.w_work * site.waiting_work / site.capacity
-        + weights.w_load * site.load
+        weights.w_queue * queue_length / capacity
+        + weights.w_work * waiting_work / capacity
+        + weights.w_load * load
     )
 
 

@@ -51,7 +51,9 @@ class Job:
     While the job waits in a ``MultilevelFeedbackQueues``, ``priority``
     and ``queue`` read its (user, t) class's shared cell. Writing either
     one then gives the job its own value until the queue's next arrival
-    recomputes it, as that arrival recomputes every queued job.
+    recomputes it, as that arrival recomputes every queued job. The
+    queue reads ``t`` and ``compute_work`` into its running totals as
+    the job enters and leaves, so neither may change while it waits.
     """
 
     user: str
@@ -166,6 +168,8 @@ class MultilevelFeedbackQueues:
         self._users: dict[str, int] = {}   # queued jobs per user
         self._t_sum = 0        # Σ t over queued jobs whose t is a non-negative integer
         self._t_inexact = 0    # queued jobs whose t is not
+        self._work_sum = 0     # Σ compute_work over queued jobs whose work is a non-negative integer
+        self._work_inexact = 0  # queued jobs whose work is not
         self._order = itertools.count()
         self._arrivals = 0
         self._services = 0
@@ -207,6 +211,11 @@ class MultilevelFeedbackQueues:
             self._t_inexact += 1
         else:
             self._t_sum += tw
+        ww = _whole(job.compute_work)
+        if ww is None:
+            self._work_inexact += 1
+        else:
+            self._work_sum += ww
 
     def _leave(self, job: Job) -> None:
         """Take a queued job out, its priority and band frozen into it."""
@@ -227,6 +236,11 @@ class MultilevelFeedbackQueues:
             self._t_inexact -= 1
         else:
             self._t_sum -= tw
+        ww = _whole(job.compute_work)
+        if ww is None:
+            self._work_inexact -= 1
+        else:
+            self._work_sum -= ww
 
     def _attach(self, entry: tuple) -> _Class:
         job = entry[3]
@@ -391,6 +405,16 @@ class MultilevelFeedbackQueues:
     def __len__(self) -> int:
         return len(self._jobs)
 
+    def queued_work(self) -> float:
+        """Σ ``compute_work`` over the queued jobs, with the bits of the
+        in-order ``sum``: from the running total while every queued work
+        is whole and the total below ``_EXACT``, else that sum, O(L)."""
+        if not self._work_inexact and self._work_sum < _EXACT:
+            return float(self._work_sum)
+        if trace.on:
+            trace.count("diana.mlfq.work_fallback")
+        return sum(j.compute_work for j in self.jobs)
+
     def jobs_ahead(self, p: float) -> int:
         """§IX: number of queued jobs with priority ≥ p."""
         n = sum(cls.n for cls in self._classes.values() if cls.priority >= p)
@@ -403,11 +427,13 @@ class MultilevelFeedbackQueues:
     # -- copying --------------------------------------------------------------
     def __getstate__(self) -> dict:
         """The id()-keyed maps travel as lists, so that a copy keys them
-        by the ids of its own jobs; ``jobs`` is rebuilt as a view."""
+        by the ids of its own jobs; ``jobs`` is rebuilt as a view, and
+        the entry counter travels as the next number it gives."""
         state = dict(self.__dict__)
         del state["jobs"]
         for k in ("_jobs", "_entry", "_loose"):
             state[k] = list(state[k].values())
+        state["_order"] = next(self._order)
         return state
 
     def __setstate__(self, state: dict) -> None:
@@ -415,6 +441,7 @@ class MultilevelFeedbackQueues:
         self._jobs = {id(j): j for j in state["_jobs"]}
         self._entry = {id(e[3]): e for e in state["_entry"]}
         self._loose = {id(e[3]): e for e in state["_loose"]}
+        self._order = itertools.count(state["_order"])
         self.jobs = self._jobs.values()
 
     # -- rates / congestion ---------------------------------------------------

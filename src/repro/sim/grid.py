@@ -42,6 +42,7 @@ from repro.core import (
 from repro.core import trace
 from repro.core.batch import comp_site_column
 from repro.core.bulk import stable_user_peer
+from repro.core.costs import computation_cost_of
 from repro.core.migration import (
     MigrationDecision,
     apply_migration,
@@ -181,8 +182,9 @@ class _Site:
         return len(self.mlfq) if self.use_mlfq else len(self.fifo)
 
     def queued_work(self) -> float:
-        jobs = self.mlfq.jobs if self.use_mlfq else self.fifo
-        return sum(j.compute_work for j in jobs)
+        if self.use_mlfq:
+            return self.mlfq.queued_work()
+        return sum(j.compute_work for j in self.fifo)
 
     def state(self) -> SiteState:
         return SiteState(
@@ -267,9 +269,8 @@ class GridSim:
         self._sp_dirty: Optional[set[str]] = None  # cols to re-read next tick
         self._mig_prio_cache: dict[str, np.ndarray] = {}
         # Per-site computation-cost value cache (see _comp_base_vec):
-        # recomputed-from-state on demand for dirtied columns only —
-        # value caching (never incremental float updates) keeps it
-        # bit-identical to full recomputation.
+        # recomputed from the site's scalars on demand for dirtied
+        # columns only, bit-identical to full recomputation.
         self._cap_vec = np.asarray(
             [float(self.sites[n].nodes) for n in self._names_sorted]
         )
@@ -285,7 +286,7 @@ class GridSim:
         self._run_token: dict[int, int] = {}
         self._token_seq = itertools.count()
         self._comp_base: Optional[np.ndarray] = None
-        self._comp_ok: Optional[np.ndarray] = None
+        self._comp_dirty: Optional[set[int]] = None  # columns to recompute
         self._stats: Optional[StreamStats] = None   # active run's accumulators
         self._collect: Optional[list[SimJob]] = None
 
@@ -497,9 +498,9 @@ class GridSim:
         path (_admit enqueue, _start, _on_finish, migration moves) calls
         this; the batch-vs-sequential equivalence suites double as
         invalidation-completeness tests."""
-        ok = self._comp_ok
-        if ok is not None:
-            ok[self._site_idx[name]] = False
+        dirty = self._comp_dirty
+        if dirty is not None:
+            dirty.add(self._site_idx[name])
         sd = self._sp_dirty
         if sd is not None:
             sd.add(name)
@@ -508,21 +509,28 @@ class GridSim:
         """Per-site ``computation_cost(state())`` column over sorted-name
         order, value-cached with dirty invalidation.
 
-        Cached entries are *recomputed from fresh state* whenever their
-        site was touched — never incrementally updated — so each value
-        is the exact float the sequential path's ``placement_cost``
-        computes (an unchanged queue re-sums to the identical float;
-        a ``+=``/``-=`` running total would not be bit-identical)."""
-        base, ok = self._comp_base, self._comp_ok
+        A touched site's entry is recomputed from its scalars
+        (``computation_cost_of``), so each value is the exact float the
+        sequential path's ``placement_cost`` computes. The queued work
+        among them is a running total
+        (``MultilevelFeedbackQueues.queued_work``), exact while every
+        queued work is a non-negative whole number and the total below
+        2**53: every partial sum in any order is then an exact integer.
+        Otherwise it is the in-order sum over the queue."""
+        base, dirty = self._comp_base, self._comp_dirty
         if base is None:
             S = len(self._names_sorted)
             base = self._comp_base = np.empty(S)
-            ok = self._comp_ok = np.zeros(S, bool)
-        if not ok.all():
-            for i in np.flatnonzero(~ok):
-                st = self.sites[self._names_sorted[i]].state()
-                base[i] = computation_cost(st, self.weights)
-            ok[:] = True
+            dirty = self._comp_dirty = set(range(S))
+        if dirty:
+            sites, names = self.sites, self._names_sorted
+            for i in dirty:
+                s = sites[names[i]]
+                base[i] = computation_cost_of(
+                    float(s.queue_len()), s.queued_work() + s.running_work,
+                    s.busy / s.nodes, float(s.nodes), self.weights,
+                )
+            dirty.clear()
         return base
 
     def _comp_vec(self, sj: SimJob) -> np.ndarray:
@@ -593,7 +601,7 @@ class GridSim:
             self._stats = StreamStats()
             # Derived-value caches never survive into a run: the caller may
             # have mutated site state between runs.
-            self._comp_base = self._comp_ok = None
+            self._comp_base = self._comp_dirty = None
             self._sp = None
             self._sp_dirty = None
             self._collect = [] if input_list is None and self.config.retain_jobs else None

@@ -1,4 +1,5 @@
 """§VI/§VII/§X multilevel feedback queues."""
+import copy
 from collections import Counter
 from types import SimpleNamespace
 
@@ -12,6 +13,7 @@ except ImportError:                      # offline CI: vendored shim
 
 from repro.core import Job, MultilevelFeedbackQueues, is_congested
 from repro.core import priority as prio
+from repro.core import trace
 
 
 def test_fig6_walkthrough_via_queues():
@@ -273,6 +275,85 @@ def test_class_queues_match_the_reference_bit_for_bit(regime, seed):
         assert [j.job_id for j in q.low_priority_jobs()] == [
             j.job_id for j in ref.jobs if j.queue == prio.NUM_QUEUES - 1
         ]
+
+
+# compute_work values per regime: whole ones take the running total; a
+# fractional one queued, or a total at or above 2**53, the in-order sum.
+# "mixed_then_whole" queues both, then drains the fractional ones and
+# goes on with whole works, back on the running total.
+_WORKS = {
+    "whole": ([30.0, 1, 0.0, 7.0, 120, 3.0], []),
+    "fractional": ([0.1, 1 / 3, 2.5, 30.0, 1e-9], []),
+    "huge": ([2.0**53, 2**60, 3.0, 1.0, 2.0**52], []),
+    "mixed_then_whole": ([30.0, 0.1, 2, 1 / 3, 5.0], [30.0, 2, 5.0, 1.0]),
+}
+
+
+@pytest.fixture
+def tracing_on():
+    trace.enable()
+    trace.reset()
+    try:
+        yield
+    finally:
+        trace.disable()
+        trace.reset()
+
+
+@pytest.mark.parametrize("regime", sorted(_WORKS))
+@pytest.mark.parametrize("seed", [0, 1])
+def test_queued_work_is_the_in_order_sum_bit_for_bit(tracing_on, regime, seed):
+    """Random interleavings of submit, submit_batch, pop_next, remove,
+    requeue and deep copies: ``queued_work()`` has the bits of the
+    builtin ``sum`` over ``jobs`` after every one, and only reads with
+    a fractional work queued, or a total at or above 2**53, fall back
+    (``diana.mlfq.work_fallback``)."""
+    first, then = _WORKS[regime]
+    rng = np.random.default_rng(seed)
+    q = MultilevelFeedbackQueues({"a": 1.0, "b": 3.0})
+    popped, k = [], 0
+
+    def check():
+        got, ref = q.queued_work(), sum(j.compute_work for j in q.jobs)
+        assert float(got).hex() == float(ref).hex() and got == ref
+
+    def walk(works, steps):
+        nonlocal q, k
+        for _ in range(steps):
+            op = rng.choice(["submit", "batch", "pop", "pop", "remove", "requeue", "copy"])
+            made = []
+            for _ in range({"submit": 1, "batch": rng.integers(2, 5)}.get(op, 0)):
+                k += 1
+                made.append(Job(user="ab"[rng.integers(2)], t=float(rng.integers(1, 3)),
+                                submit_time=float(rng.integers(0, 8)), job_id=k,
+                                compute_work=works[rng.integers(len(works))]))
+            if op == "submit":
+                q.submit(made[0])
+            elif op == "batch":
+                q.submit_batch(made)
+            elif op == "pop":
+                job = q.pop_next()
+                if job is not None:
+                    popped.append(job)
+            elif op == "remove" and len(q):
+                q.remove(list(q.jobs)[rng.integers(len(q))])
+            elif op == "requeue" and popped:
+                q.requeue(popped.pop(rng.integers(len(popped))))
+            elif op == "copy":
+                q = copy.deepcopy(q)
+            check()
+
+    walk(first, 300)
+    fallbacks = trace.counters().get("diana.mlfq.work_fallback", 0)
+    assert (fallbacks == 0) == (regime == "whole")
+    if then:
+        for j in [j for j in q.jobs if j.compute_work % 1]:
+            q.remove(j)
+        popped = [j for j in popped if j.compute_work % 1 == 0]
+        check()
+        trace.reset()
+        walk(then, 300)
+        assert trace.counters().get("diana.mlfq.work_fallback", 0) == 0
 
 
 def test_priority_written_on_a_queued_job_holds_until_the_next_submit():

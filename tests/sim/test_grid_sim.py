@@ -625,3 +625,91 @@ class TestBatchedMigration:
         assert [j.finish for j in seq.jobs] == [j.finish for j in bat.jobs]
         assert seq.timeline == bat.timeline
         assert bat.migrations() > 0
+
+
+# Work per job: whole seconds take the queues' running total, lognormal
+# work the in-order sum (``diana.mlfq.work_fallback``).
+_COLUMN_WORK = {
+    "whole": lambda rng: float(np.ceil(rng.lognormal(4.0, 1.0))),
+    "lognormal": lambda rng: float(rng.lognormal(4.0, 1.0)),
+}
+
+
+def _column_workload(work, seed=3):
+    """Bursts from a low-quota hog and a steady polite stream: queues
+    deepen, sites congest and §IX migrates."""
+    rng = np.random.default_rng(seed)
+    jobs = [SimJob(user="hog", arrival=float(b * 30), work=work(rng), input_bytes=2e9,
+                   data_site="site1", origin_site="site1")
+            for b in range(6) for _ in range(30)]
+    jobs += [SimJob(user="polite", arrival=float(i * 20), work=work(rng), input_bytes=2e9,
+                    data_site="site3", origin_site="site1")
+             for i in range(40)]
+    return sorted(jobs, key=lambda j: j.arrival)
+
+
+class _ColumnCheckedSim(GridSim):
+    """Checks after every arrival batch that each entry of the cached
+    §IV computation column is ``computation_cost`` over a ``SiteState``
+    whose waiting work is the in-order sum over the site's queue."""
+
+    checked = 0
+
+    def _on_arrive(self, sj, now, events):
+        super()._on_arrive(sj, now, events)
+        self._check_column()
+
+    def _on_arrive_batch(self, batch, now, events):
+        super()._on_arrive_batch(batch, now, events)
+        self._check_column()
+
+    def _check_column(self):
+        from repro.core import SiteState, computation_cost
+
+        base = self._comp_base_vec()
+        for i, name in enumerate(self._names_sorted):
+            s = self.sites[name]
+            st = SiteState(
+                name=name, capacity=float(s.nodes), queue_length=float(len(s.mlfq)),
+                waiting_work=sum(j.compute_work for j in s.mlfq.jobs) + s.running_work,
+                load=s.busy / s.nodes,
+            )
+            assert float(base[i]).hex() == computation_cost(st, self.weights).hex()
+        self.checked += 1
+
+
+@pytest.mark.parametrize("weights", [None, (0.7, 1.3, 0.1), (0.7, 1e-4, 0.1)])
+@pytest.mark.parametrize("work", sorted(_COLUMN_WORK))
+def test_computation_column_is_the_in_order_recompute(work, weights):
+    """The column refreshed from each site's scalars and running queued
+    work equals an in-order recompute after every arrival batch, through
+    a site's death and return, and the default loop decides every job as
+    the per-event loop does."""
+    from repro.core import CostWeights, trace
+    from repro.sim import SimConfig
+    from repro.sim.faults import FaultPlan
+
+    plan = FaultPlan().site_down(100.0, "site1").site_up(400.0, "site1")
+    extra = {} if weights is None else {"weights": CostWeights(*weights)}
+    jobs = _column_workload(_COLUMN_WORK[work])
+    runs = []
+    trace.enable()
+    trace.reset()
+    try:
+        for loop in ({}, {"horizon": False, "batch_arrivals": False}):
+            cfg = SimConfig(policy="diana", quotas=QUOTAS, migration_interval_s=30.0,
+                            congestion_window_s=120.0, fault_plan=plan, **extra, **loop)
+            sim = _ColumnCheckedSim(paper_grid_spec(), config=cfg)
+            res = sim.run(copy.deepcopy(jobs))
+            assert sim.checked >= len({j.arrival for j in jobs})
+            assert all(j.finish >= 0 for j in res.jobs)
+            runs.append(res)
+        fallbacks = trace.counters().get("diana.mlfq.work_fallback", 0)
+    finally:
+        trace.disable()
+        trace.reset()
+    assert (fallbacks > 0) == (work == "lognormal")
+    assert runs[0].migrations() > 0 and sum(j.requeues for j in runs[0].jobs) > 0
+    assert [(j.exec_site, j.finish, j.migrated) for j in runs[0].jobs] == [
+        (j.exec_site, j.finish, j.migrated) for j in runs[1].jobs
+    ]
